@@ -42,13 +42,6 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
-
-cache = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), ".jax_cache")
-jax.config.update("jax_compilation_cache_dir", cache)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-
 import numpy as np
 
 from bench import _bench_metrics
@@ -56,6 +49,7 @@ from sparkrdma_tpu import MeshRuntime, ShuffleConf
 from sparkrdma_tpu.api.serde import (decode_bytes_rows, encode_bytes_rows,
                                      payload_words)
 from sparkrdma_tpu.api.shuffle_manager import ShuffleManager
+from sparkrdma_tpu.utils.compile_cache import enable_compile_cache
 from sparkrdma_tpu.workloads.terasort import run_terasort
 
 MAX_PAYLOAD = 92
@@ -76,6 +70,7 @@ def main(argv=None) -> int:
                     help="write the exchange journal (spans + rollup "
                          "windows) to PATH")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     n = int(os.environ.get("BENCH_RECORDS_PER_DEVICE", 8 * 1024 * 1024))
     repeats = int(os.environ.get("BENCH_REPEATS", 8))
     rng = np.random.default_rng(7)

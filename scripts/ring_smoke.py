@@ -20,10 +20,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from sparkrdma_tpu.utils.compat import shard_map
 from sparkrdma_tpu.utils.stats import barrier
 
 
@@ -42,8 +41,6 @@ def main() -> int:
     from jax.experimental.pallas import tpu as pltpu
 
     from sparkrdma_tpu.exchange.ring import _a2a_kernel
-    from sparkrdma_tpu.utils.compat import (shape_dtype_struct,
-                                            tpu_compiler_params)
 
     per = 1 << 20
     w = 4
@@ -55,14 +52,14 @@ def main() -> int:
             kernel,
             in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
-            out_shape=shape_dtype_struct(slots.shape, slots.dtype,
-                                         vma=frozenset({"shuffle"})),
+            out_shape=jax.ShapeDtypeStruct(slots.shape, slots.dtype,
+                                           vma=frozenset({"shuffle"})),
             scratch_shapes=[
                 pltpu.SemaphoreType.DMA((n,)),
                 pltpu.SemaphoreType.DMA((n,)),
                 pltpu.SemaphoreType.DMA,
             ],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 has_side_effects=True,
                 # collective_id is only legal with the barrier-semaphore
                 # handshake, which needs >1 device

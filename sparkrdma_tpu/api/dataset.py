@@ -93,9 +93,8 @@ def _join_program(manager: ShuffleManager, ca: int, cb: int,
     if fn is not None:
         return fn
 
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from sparkrdma_tpu.utils.compat import shard_map
     from sparkrdma_tpu.workloads.join import _local_join
 
     rt = manager.runtime
@@ -150,9 +149,8 @@ def _join_rows_program(manager: ShuffleManager, ca: int, cb: int,
     if fn is not None:
         return fn
 
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from sparkrdma_tpu.utils.compat import shard_map
     from sparkrdma_tpu.workloads.join import _local_join_rows
 
     rt = manager.runtime
@@ -554,9 +552,8 @@ class Dataset:
         ck = ("count", cap, self.records.shape[0])
         fn = cache.get(ck)
         if fn is None:
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
-
-            from sparkrdma_tpu.utils.compat import shard_map
 
             rt = m.runtime
             ax = rt.axis_name
@@ -672,9 +669,8 @@ class Dataset:
         ck = ("densify", cap, new_cap, w)
         fn = cache.get(ck)
         if fn is None:
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
-
-            from sparkrdma_tpu.utils.compat import shard_map
 
             rt = m.runtime
             ax = rt.axis_name
@@ -731,9 +727,8 @@ class Dataset:
         ck = ("pending", cap, w, fkey, keep_words)
         fn = cache.get(ck)
         if fn is None:
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
-
-            from sparkrdma_tpu.utils.compat import shard_map
 
             rt = m.runtime
             ax = rt.axis_name
@@ -903,10 +898,10 @@ class Dataset:
         ck = ("distinct", cap, w)
         fn = cache.get(ck)
         if fn is None:
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
             from sparkrdma_tpu.kernels.aggregate import combine_by_key_cols
-            from sparkrdma_tpu.utils.compat import shard_map
 
             rt = m.runtime
             ax = rt.axis_name
@@ -973,10 +968,10 @@ class Dataset:
         if fn is not None:
             return fn
 
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from sparkrdma_tpu.kernels.group import group_runs_cols
-        from sparkrdma_tpu.utils.compat import shard_map
 
         rt = m.runtime
         ax = rt.axis_name
@@ -1041,10 +1036,10 @@ class Dataset:
         ck = ("cogroup", ca, cb, kw)
         fn = cache.get(ck)
         if fn is None:
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
             from sparkrdma_tpu.kernels.group import cogroup_tables
-            from sparkrdma_tpu.utils.compat import shard_map
 
             rt = m.runtime
             ax = rt.axis_name

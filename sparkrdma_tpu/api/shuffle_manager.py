@@ -1168,9 +1168,8 @@ class ShuffleManager:
         key = (cap, out.shape[0])
         fn = self._filter_cache.get(key)
         if fn is None:
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
-
-            from sparkrdma_tpu.utils.compat import shard_map
 
             ax = self.runtime.axis_name
 
@@ -1232,10 +1231,10 @@ class ShuffleManager:
         key = ("splitfilter", cap, w, s_total, mode)
         fn = self._filter_cache.get(key)
         if fn is None:
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
             from sparkrdma_tpu.kernels.sort import sort_by_lead_cols
-            from sparkrdma_tpu.utils.compat import shard_map
 
             ax = self.runtime.axis_name
             sentinel = jnp.uint32(0xFFFFFFFF)
@@ -1278,9 +1277,8 @@ class ShuffleManager:
         key = ("agg", cap, out.shape[0], key_words, op, float_payload)
         fn = self._filter_cache.get(key)
         if fn is None:
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
-
-            from sparkrdma_tpu.utils.compat import shard_map
 
             ax = self.runtime.axis_name
 
@@ -1311,9 +1309,8 @@ class ShuffleManager:
         key = (cap, w, key_words)
         fn = self._sort_cache.get(key)
         if fn is None:
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
-
-            from sparkrdma_tpu.utils.compat import shard_map
 
             ax = self.runtime.axis_name
 
@@ -1331,8 +1328,9 @@ class ShuffleManager:
             def local_sort(cols, total):
                 valid = jnp.arange(cap) < total[0]
                 if fast:   # same contract note as the fused tail
-                    return merge_sort_cols(cols, valid,
-                                           run=self.conf.fast_sort_run)
+                    return merge_sort_cols(
+                        cols, valid, run=self.conf.fast_sort_run,
+                        interpret=self._exchange.interpret)
                 if pack:
                     return packed_lexsort_cols(
                         cols, key_words, valid,

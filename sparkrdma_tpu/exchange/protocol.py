@@ -64,7 +64,7 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from sparkrdma_tpu.config import (ShuffleConf, size_class,
@@ -80,7 +80,7 @@ from sparkrdma_tpu.obs.metrics import MetricsRegistry
 from sparkrdma_tpu.obs.stats import ExchangeRecord, ShuffleReadStats
 from sparkrdma_tpu.obs.timeline import NULL_TIMELINE, EventTimeline
 from sparkrdma_tpu.obs.watchdog import StallWatchdog
-from sparkrdma_tpu.utils.compat import shard_map
+from sparkrdma_tpu.runtime.mesh import mesh_interpret
 
 
 @dataclasses.dataclass(frozen=True)
@@ -205,6 +205,10 @@ class ShuffleExchange:
         self.axis_name = axis_name
         self.conf = conf or ShuffleConf()
         self.mesh_size = int(mesh.shape[axis_name])
+        #: Pallas kernels of this exchange (ring transport, merge sort)
+        #: run interpreted: follows the mesh's devices, never the
+        #: default backend
+        self.interpret = mesh_interpret(mesh)
         # multi-tenant service identity: spans carry it, exec-cache and
         # collective-id keys fold it in (two tenants' identically-shaped
         # exchanges must not alias), and the account meters HBM buffers
@@ -666,7 +670,8 @@ class ShuffleExchange:
                 # opt-in via conf.stable_key_sort (which disables this
                 # kernel and the unstable fallback below).
                 out = merge_sort_cols(out, valid,
-                                      run=self.conf.fast_sort_run)
+                                      run=self.conf.fast_sort_run,
+                                      interpret=self.interpret)
             elif mode == "pack":
                 out = packed_lexsort_cols(
                     out, sort_key_words, valid,

@@ -47,12 +47,16 @@ bit-equal to per-round ``lax.all_to_all`` across 1/2/5 rounds, ragged
 last rounds, and the 1-device degenerate case, and the full
 ``transport="pallas_ring"`` exchange is pinned bit-equal to
 ``transport="xla"`` for repartition, terasort, and streaming-regime
-shapes. ``scripts/ring_smoke.py`` exercises the Mosaic-lowered
-local-DMA + semaphore path on a single real chip; the POD-READINESS
-pack is ``scripts/ring_pod.py``: where ``len(jax.devices()) >= 2`` it
-executes the remote-DMA + barrier legs — including a fused multi-round
-leg — end to end and asserts parity against ``lax.all_to_all``; until
-then it refuses loudly instead of pretending.
+shapes. ``tests/test_tpu_compile.py`` compiles the fused kernel for a
+described v5e at record widths 13 and 25 (Mosaic lowering, no chip),
+and ``chip_smoke.py --chips 4`` runs a 100-byte TeraSort through it on
+four chips against the xla transport. ``scripts/ring_smoke.py``
+exercises the Mosaic-lowered local-DMA + semaphore path on a single
+real chip; the POD-READINESS pack is ``scripts/ring_pod.py``: where
+``len(jax.devices()) >= 2`` it executes the remote-DMA + barrier legs —
+including a fused multi-round leg — end to end and asserts parity
+against ``lax.all_to_all``; with fewer chips it refuses loudly instead
+of pretending.
 """
 
 from __future__ import annotations
@@ -67,7 +71,29 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from sparkrdma_tpu.utils.compat import shape_dtype_struct, tpu_compiler_params
+from sparkrdma_tpu.runtime.mesh import mesh_interpret
+
+#: the (sublane, lane) tile of a uint32 array in TPU memory. Mosaic
+#: slices an HBM ref only along its untiled leading dims, so every block
+#: the ring kernels DMA must fill whole tiles in its two minor dims.
+_TILE = (8, 128)
+
+
+def _tile_blocks(slots: jax.Array) -> jax.Array:
+    """Pad the two minor dims (record width W, slot capacity C — the
+    ``[..., W, C]`` of every per-destination block) up to whole
+    ``_TILE`` tiles. It removes a block slice Mosaic refuses
+    (W % 8 != 0, e.g. the 100-byte W=25) and any reshape across
+    unaligned minor dims, which XLA would lower as an element-granular
+    relayout (hundreds of MB of code at 16M records). It is not free:
+    this pad and the slice that drops it after the kernel each write a
+    full copy of the slot tensor (32 of 25 rows at W=25) on every ring
+    exchange. The wire protocol (counts lane, slot order) never sees
+    the pad."""
+    w, c = slots.shape[-2:]
+    pad = [(0, 0)] * (slots.ndim - 2) + [(0, -w % _TILE[0]),
+                                         (0, -c % _TILE[1])]
+    return jnp.pad(slots, pad) if any(p for _, p in pad) else slots
 
 
 def derive_collective_id(key) -> int:
@@ -241,7 +267,7 @@ def make_ring_exchange(mesh, axis_name: str, num_rounds: int,
     if metrics is None:
         metrics = MetricsRegistry(enabled=False)
     num_devices = int(mesh.shape[axis_name])
-    interpret = jax.default_backend() != "tpu"
+    interpret = mesh_interpret(mesh)
 
     def exchange(slots: jax.Array) -> jax.Array:
         if slots.shape[0] != num_rounds:
@@ -257,24 +283,26 @@ def make_ring_exchange(mesh, axis_name: str, num_rounds: int,
         kernel = partial(_ring_exchange_kernel, axis_name=axis_name,
                          num_devices=num_devices, num_rounds=num_rounds,
                          collective=not interpret)
-        return pl.pallas_call(
+        tiled = _tile_blocks(slots)
+        out = pl.pallas_call(
             kernel,
             in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
-            out_shape=shape_dtype_struct(slots.shape, slots.dtype,
-                                         vma=frozenset({axis_name})),
+            out_shape=jax.ShapeDtypeStruct(tiled.shape, tiled.dtype,
+                                           vma=frozenset({axis_name})),
             scratch_shapes=[
                 # parity banks: [2, P] send/recv completions per round
                 pltpu.SemaphoreType.DMA((2, num_devices)),
                 pltpu.SemaphoreType.DMA((2, num_devices)),
                 pltpu.SemaphoreType.DMA((2,)),  # local copies, per bank
             ],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 has_side_effects=True,
                 collective_id=collective_id,
             ),
             interpret=interpret,
-        )(slots)
+        )(tiled)
+        return out[..., :slots.shape[-2], :slots.shape[-1]]
 
     return exchange
 
@@ -298,7 +326,7 @@ def make_ring_all_to_all(mesh, axis_name: str,
     if metrics is None:
         metrics = MetricsRegistry(enabled=False)
     num_devices = int(mesh.shape[axis_name])
-    interpret = jax.default_backend() != "tpu"
+    interpret = mesh_interpret(mesh)
 
     def a2a(slots: jax.Array) -> jax.Array:
         if num_devices == 1:
@@ -307,23 +335,25 @@ def make_ring_all_to_all(mesh, axis_name: str,
         kernel = partial(_a2a_kernel, axis_name=axis_name,
                          num_devices=num_devices,
                          collective=not interpret)
-        return pl.pallas_call(
+        tiled = _tile_blocks(slots)
+        out = pl.pallas_call(
             kernel,
             in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
-            out_shape=shape_dtype_struct(slots.shape, slots.dtype,
-                                         vma=frozenset({axis_name})),
+            out_shape=jax.ShapeDtypeStruct(tiled.shape, tiled.dtype,
+                                           vma=frozenset({axis_name})),
             scratch_shapes=[
                 pltpu.SemaphoreType.DMA((num_devices,)),  # send completions
                 pltpu.SemaphoreType.DMA((num_devices,)),  # recv completions
                 pltpu.SemaphoreType.DMA,                  # local copy
             ],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 has_side_effects=True,
                 collective_id=collective_id,
             ),
             interpret=interpret,
-        )(slots)
+        )(tiled)
+        return out[..., :slots.shape[-2], :slots.shape[-1]]
 
     return a2a
 

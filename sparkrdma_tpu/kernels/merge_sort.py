@@ -48,7 +48,8 @@ Padding handling: rows with ``valid == False`` are lifted to all-ones
 (0xFFFFFFFF...) so they sort to the tail as a block, then zeroed back
 after the sort — the same contract as ``lexsort_cols``'s validity lead.
 
-The kernel runs compiled on TPU and in interpret mode on CPU (tests).
+The kernel runs compiled on TPU and in interpret mode on CPU (tests);
+the caller says which.
 """
 
 from __future__ import annotations
@@ -121,11 +122,12 @@ def _reverse_cols(cols, length):
     return cols
 
 
-def _bitonic_merge_cols(cols, length):
+def _bitonic_merge_cols(cols, length, words):
     """Merge a bitonic sequence ``cols: [W, length]`` ascending in VMEM.
 
     ``length`` must be a power of two. Full-record comparator: the swap
-    decision uses all W words; all W words move together. Strides >=
+    decision uses the leading ``words`` rows (the record; any rows
+    below are tile padding); all W rows move together. Strides >=
     128 use reshape-pair compare-exchange; smaller strides exchange
     partners via lane rolls (Mosaic reshape limit).
     """
@@ -135,7 +137,8 @@ def _bitonic_merge_cols(cols, length):
         blocks = length // (2 * stride)
         x = cols.reshape(w, blocks, 2, stride)
         a, b = x[:, :, 0, :], x[:, :, 1, :]
-        swap = _lex_lt([b[i] for i in range(w)], [a[i] for i in range(w)])
+        swap = _lex_lt([b[i] for i in range(words)],
+                       [a[i] for i in range(words)])
         lo = jnp.where(swap, b, a)
         hi = jnp.where(swap, a, b)
         cols = jnp.stack([lo, hi], axis=2).reshape(w, length)
@@ -147,8 +150,8 @@ def _bitonic_merge_cols(cols, length):
     while stride >= 1:
         partner = _xor_partner_grouped(g, stride)
         low = (lane & stride) == 0
-        xw = [g[i] for i in range(w)]
-        pw = [partner[i] for i in range(w)]
+        xw = [g[i] for i in range(words)]
+        pw = [partner[i] for i in range(words)]
         p_lt_x = _lex_lt(pw, xw)                 # [groups, 128]
         x_lt_p = _lex_lt(xw, pw)
         # logical blend, not where-on-bools: a select with boolean
@@ -307,7 +310,8 @@ def _window(cols_ref, win, tail, sems, start_aligned, shift, tile, w):
 
 
 def _stage_kernel(aoff_ref, cols_ref, out_ref, a_win, a_tail, b_win,
-                  b_tail, sem_a, sem_at, sem_b, sem_bt, *, run, tile, w):
+                  b_tail, sem_a, sem_at, sem_b, sem_bt, *, run, tile, w,
+                  words):
     """One output tile of one merge stage.
 
     ``cols_ref``: the full padded array [W, n + 2*tile] in HBM/ANY.
@@ -348,12 +352,13 @@ def _stage_kernel(aoff_ref, cols_ref, out_ref, a_win, a_tail, b_win,
     # ascending ++ descending = bitonic
     cand = jnp.concatenate([ca, _reverse_cols(cb, tile)],
                            axis=1)                       # [W, 2*tile]
-    merged = _bitonic_merge_cols(cand, 2 * tile)
+    merged = _bitonic_merge_cols(cand, 2 * tile, words)
     out_ref[...] = jnp.where(is_pad, _FULL, merged[:, :tile])
 
 
 def _merge_stage(cols_padded: jax.Array, aoff: jax.Array, *, n: int,
-                 run: int, tile: int, interpret: bool) -> jax.Array:
+                 run: int, tile: int, words: int,
+                 interpret: bool) -> jax.Array:
     """Dispatch one merge stage; returns the new padded array
     [W, n + 2*tile].
 
@@ -364,7 +369,8 @@ def _merge_stage(cols_padded: jax.Array, aoff: jax.Array, *, n: int,
     w = cols_padded.shape[0]
     n_tiles = n // tile
 
-    kernel = functools.partial(_stage_kernel, run=run, tile=tile, w=w)
+    kernel = functools.partial(_stage_kernel, run=run, tile=tile, w=w,
+                               words=words)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_tiles + 2,),
@@ -393,13 +399,21 @@ def _merge_stage(cols_padded: jax.Array, aoff: jax.Array, *, n: int,
 # ----------------------------------------------------------------------
 # public entry
 # ----------------------------------------------------------------------
+#: scoped VMEM the stage kernel holds per record word per tile column:
+#: the double-buffered output block, both windows, and the in-kernel
+#: values (rolled windows, the 2*tile candidate, merge temporaries).
+#: Compiled for v5e, W=8 at tile 32768 asked for 18.81 MiB (~18.8 x 4 B
+#: per word-column); 20 x 4 B leaves headroom.
+_VMEM_BYTES_PER_WORD_COL = 20 * 4
+#: share of Mosaic's 16 MiB default scoped-VMEM limit the tile may fill
+_VMEM_BUDGET = 12 * 1024 * 1024
+
+
 def _pick_tile(w: int) -> int:
-    """Largest power-of-two tile (multiple of 128) whose kernel working
-    set (~2 windows + 2x-candidate merge buffers ~ 8*w*tile*4B) fits
-    comfortably in ~12MB of the ~16MB VMEM."""
-    budget = 12 * 1024 * 1024
+    """Largest power-of-two tile (multiple of 128, at most 2^15) whose
+    real scoped-VMEM footprint fits the budget."""
     tile = 1 << 15
-    while 8 * w * tile * 4 > budget and tile > 128:
+    while _VMEM_BYTES_PER_WORD_COL * w * tile > _VMEM_BUDGET and tile > 128:
         tile //= 2
     return tile
 
@@ -414,7 +428,7 @@ def merge_sort_cols(
     valid: Optional[jax.Array] = None,
     run: int = 1 << 15,
     tile: Optional[int] = None,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Sort columnar records ``uint32[W, N]`` by full-record lexicographic
     order (ascending). See module docstring for the algorithm and the
@@ -423,11 +437,14 @@ def merge_sort_cols(
     ``valid``: bool[N] — invalid rows sort to the tail and are zeroed.
     ``run``: initial XLA-sorted run length (power of two).
     ``tile``: merge kernel tile (default: auto from VMEM budget).
-    ``interpret``: force Pallas interpret mode (defaults to True off-TPU).
+    ``interpret``: run the merge kernel in Pallas interpret mode — the
+    caller's choice (a test, or :func:`~sparkrdma_tpu.runtime.mesh.
+    mesh_interpret` of the mesh it runs on), never inferred here.
     """
     w, n = cols.shape
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+    # the kernel's refs carry whole 8-row tiles: Mosaic refuses a
+    # window slice of, e.g., the 25 rows of a 100-byte record
+    rows = -(-w // 8) * 8
     if run < _Q or run & (run - 1):
         # the coarse search's 128-quantum and the window-tail stitch
         # both assume a pow2 run of at least one lane group
@@ -437,7 +454,7 @@ def merge_sort_cols(
         raise ValueError(
             f"merge_sort_cols needs power-of-two N >= {2*run}, got {n}")
     if tile is None:
-        tile = min(_pick_tile(w), run)
+        tile = min(_pick_tile(rows), run)
     if run % tile:
         raise ValueError(f"run {run} must be a multiple of tile {tile}")
 
@@ -445,18 +462,19 @@ def merge_sort_cols(
         cols = jnp.where(valid[None, :], cols, _FULL)
 
     cols = chunk_sort_cols(cols, run)
-    # padded work layout [W, N + 2*tile]: aligned B-windows of the last
-    # pair may read up to tile + 128 past the array; the pad stays
-    # all-ones across stages
-    padded = jnp.concatenate(
-        [cols, jnp.full((w, 2 * tile), _FULL, jnp.uint32)], axis=1)
+    # padded work layout [rows, N + 2*tile]: aligned B-windows of the
+    # last pair may read up to tile + 128 past the array; the pad stays
+    # all-ones across stages. Rows past W ride along, never compared.
+    padded = jnp.pad(jnp.concatenate(
+        [cols, jnp.full((w, 2 * tile), _FULL, jnp.uint32)], axis=1),
+        ((0, rows - w), (0, 0)))
     r = run
     while r < n:
-        aoff = _merge_path_offsets(padded, n, r, tile)
+        aoff = _merge_path_offsets(padded[:w], n, r, tile)
         padded = _merge_stage(padded, aoff, n=n, run=r, tile=tile,
-                              interpret=interpret)
+                              words=w, interpret=interpret)
         r *= 2
-    out = padded[:, :n]
+    out = padded[:w, :n]
 
     if valid is not None:
         total = jnp.sum(valid.astype(jnp.int32))

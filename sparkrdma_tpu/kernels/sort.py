@@ -22,9 +22,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from sparkrdma_tpu.utils.compat import enable_x64
+from jax import enable_x64, lax
 
 
 def _sort_rows(records: jax.Array, num_keys: int,

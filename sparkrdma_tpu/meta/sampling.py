@@ -17,9 +17,8 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from sparkrdma_tpu.utils.compat import shard_map
 
 from sparkrdma_tpu.kernels.sort import lexsort_records
 

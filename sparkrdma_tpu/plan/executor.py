@@ -54,6 +54,7 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from sparkrdma_tpu import faults as _faults
@@ -63,7 +64,6 @@ from sparkrdma_tpu.obs import trace as _trace
 from sparkrdma_tpu.obs.journal import SCHEMA_VERSION
 from sparkrdma_tpu.plan.nodes import LogicalPlan, PlanNode, fingerprint_hex
 from sparkrdma_tpu.plan.optimizer import optimize
-from sparkrdma_tpu.utils.compat import shard_map
 
 log = logging.getLogger("sparkrdma_tpu.plan")
 

@@ -56,6 +56,17 @@ def make_mesh(
     return Mesh(devs, (axis_name,))
 
 
+def mesh_interpret(mesh: Mesh) -> bool:
+    """Run this mesh's Pallas kernels in interpret mode?
+
+    Follows the devices the kernels will run on — compiled on TPU,
+    interpreted on anything else (the forced-host CPU test mesh) — and
+    never the process's default backend, so a mesh of described TPU
+    devices compiles the real kernel and a CPU mesh never silently
+    pretends to."""
+    return mesh.devices.flat[0].platform != "tpu"
+
+
 @dataclasses.dataclass(frozen=True)
 class ManagerId:
     """Identity of one shuffle participant — ``RdmaShuffleManagerId`` analogue.

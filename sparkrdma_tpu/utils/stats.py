@@ -11,8 +11,6 @@ from __future__ import annotations
 
 import time
 
-import numpy as np
-
 from sparkrdma_tpu.obs.stats import ExchangeRecord, ShuffleReadStats
 
 
@@ -26,31 +24,15 @@ class Timer:
 
 
 def barrier(*arrays) -> None:
-    """Hard execution barrier for timing: wait AND materialize one element.
-
-    ``jax.block_until_ready`` alone is not a reliable barrier on every
-    backend (tunneled/experimental platforms can return before the device
-    finishes); transferring a single element of each array forces the
-    producing executable to complete on any backend, at the cost of a
-    few bytes of D2H. Use at the edges of timed regions.
+    """Execution barrier for timing: wait until every producing
+    executable has finished. Use at the edges of timed regions.
 
     Accepts anything ``block_until_ready`` does: arrays of any rank
-    including 0-d (indexed with the empty tuple), zero-size arrays
-    (nothing to materialize — the block is the whole barrier), and
-    non-array leaves (skipped).
+    including 0-d, zero-size arrays, and non-array leaves (skipped).
     """
     import jax
 
-    for a in arrays:
-        jax.block_until_ready(a)
-        ndim = getattr(a, "ndim", None)
-        size = getattr(a, "size", None)
-        if ndim is None or size is None or size == 0:
-            continue  # non-array or empty: block_until_ready must do
-        try:
-            np.asarray(a[(0,) * ndim])
-        except (IndexError, TypeError):  # non-indexable sharded layout
-            pass
+    jax.block_until_ready(arrays)
 
 
 __all__ = ["ExchangeRecord", "ShuffleReadStats", "Timer", "barrier"]

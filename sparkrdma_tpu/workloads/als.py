@@ -42,9 +42,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from sparkrdma_tpu.utils.compat import shard_map
 
 from sparkrdma_tpu.exchange.partitioners import modulo_partitioner
 from sparkrdma_tpu.exchange.protocol import ShuffleExchange

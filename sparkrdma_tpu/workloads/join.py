@@ -22,9 +22,8 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from sparkrdma_tpu.utils.compat import shard_map
 
 from sparkrdma_tpu.api.shuffle_manager import ShuffleManager
 from sparkrdma_tpu.exchange.partitioners import hash_partitioner

@@ -4,9 +4,10 @@ This is the "fake backend" SparkRDMA never had (SURVEY.md §4): real
 ``all_to_all`` semantics on any machine via XLA's forced host platform,
 standing in for an 8-chip ICI mesh.
 
-Platform forcing is subtle in this deployment; the recipe (and why env
-vars alone don't work) lives in the shared ``_hostmesh`` module at the repo
-root, also used by ``__graft_entry__.dryrun_multichip``'s subprocess child.
+The forcing runs here, at import, because the mesh must exist before the
+first backend use; the recipe lives in the shared ``_hostmesh`` module at
+the repo root, also used by ``__graft_entry__.dryrun_multichip``'s
+subprocess child.
 """
 
 import os

@@ -750,12 +750,11 @@ class TestRingFusedKernel:
 
     @pytest.mark.parametrize("num_rounds", [1, 2, 5])
     def test_kernel_parity_vs_all_to_all(self, runtime, rng, num_rounds):
-        from jax import lax
+        from jax import lax, shard_map
         from jax.sharding import PartitionSpec as P
 
         from sparkrdma_tpu.exchange.ring import (derive_collective_id,
                                                  make_ring_exchange)
-        from sparkrdma_tpu.utils.compat import shard_map
 
         rt = runtime
         mesh_size = rt.num_partitions

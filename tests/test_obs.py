@@ -692,10 +692,10 @@ class TestManagerJournalE2E:
 
 class TestBarrierEdgeCases:
     def test_zero_dim_array(self):
-        barrier(jnp.asarray(3.5))              # 0-d: indexed with ()
+        barrier(jnp.asarray(3.5))              # 0-d
 
     def test_empty_array(self):
-        barrier(jnp.zeros((0,), jnp.uint32))   # nothing to materialize
+        barrier(jnp.zeros((0,), jnp.uint32))   # zero-size
         barrier(jnp.zeros((4, 0), jnp.uint32))
 
     def test_non_array_leaves(self):
