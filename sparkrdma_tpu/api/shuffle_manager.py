@@ -140,14 +140,13 @@ class ShuffleWriter:
         if not success or self._records is None:
             self._records = None
             return None
-        with self._m._tenant_scope(), Timer() as t, \
-                annotate("shuffle:plan"):
+        with self._m._tenant_scope():
             self._plan = self._m._exchange.plan(
                 self._records, self._h.partitioner, self._h.num_parts
             )
-        self._m._registry.publish_map_output(self._h.shuffle_id,
-                                             self._plan.counts)
-        self._m._plan_seconds[self._h.shuffle_id] = t.elapsed
+        with annotate("shuffle:plan/publish"):
+            self._m._registry.publish_map_output(self._h.shuffle_id,
+                                                 self._plan.counts)
         if self._m.store is not None and self._m.conf.spill_to_host:
             self._m.checkpoint_shuffle(self._h, writer=self)
         log.debug("shuffle %d map published: %d records, %d rounds",
@@ -356,7 +355,8 @@ class ShuffleReader:
                             # wrap; un-recorded reads (warmup, steady-
                             # state loops) stay async so dispatches
                             # pipeline without a host round-trip each
-                            barrier(out)
+                            with annotate("shuffle:read/barrier"):
+                                barrier(out)
                     except jax.errors.JaxRuntimeError as e:
                         # A real transport/device failure surfaces as a
                         # backend runtime error; map it to the retryable
@@ -411,7 +411,7 @@ class ShuffleReader:
         if record_stats:
             # per-source totals for the histogram (received metadata table)
             per_source = plan.counts.sum(axis=1)
-            plan_s = self._m._plan_seconds.get(self._h.shuffle_id, 0.0)
+            plan_s = plan.plan_s
             self._m.stats.add(ExchangeRecord(
                 shuffle_id=self._h.shuffle_id,
                 plan_s=plan_s,
@@ -838,7 +838,6 @@ class ShuffleManager:
                     for i in range(self.runtime.num_partitions))
         self._registry = MapOutputRegistry(ids, metrics=self.metrics)
         self._writers: dict[int, ShuffleWriter] = {}
-        self._plan_seconds: dict[int, float] = {}
         self._sort_cache: dict[tuple, Callable] = {}
         self._filter_cache: dict[tuple, Callable] = {}
 
@@ -894,21 +893,22 @@ class ShuffleManager:
             process_index=self.runtime.process_index)
 
     def unregister_shuffle(self, shuffle_id: int) -> None:
-        self._registry.unregister(shuffle_id)
-        self._writers.pop(shuffle_id, None)
-        self._plan_seconds.pop(shuffle_id, None)
-        # dispose: recycled output buffers go back to the pool (callers
-        # must have consumed this shuffle's reads by now — the reference
-        # frees registered buffers on unregisterShuffle the same way)
-        self._exchange.release_shuffle(shuffle_id)
-        # tiered-store teardown: drop this shuffle's remaining segments
-        # (host leases AND disk files). Without this, segments published
-        # via put(..., shuffle=)/adopt() outlived their shuffle until
-        # close() — pinned host bytes and .seg files leaking across the
-        # manager's lifetime.
-        self.tiered.delete_shuffle(shuffle_id, tenant=self.tenant)
-        if self.store is not None:  # shuffle files removed on unregister
-            self.store.delete(shuffle_id)
+        with annotate("shuffle:unregister"):
+            self._registry.unregister(shuffle_id)
+            self._writers.pop(shuffle_id, None)
+            # dispose: recycled output buffers go back to the pool
+            # (callers must have consumed this shuffle's reads by now —
+            # the reference frees registered buffers on unregisterShuffle
+            # the same way)
+            self._exchange.release_shuffle(shuffle_id)
+            # tiered-store teardown: drop this shuffle's remaining
+            # segments (host leases AND disk files). Without this,
+            # segments published via put(..., shuffle=)/adopt() outlived
+            # their shuffle until close() — pinned host bytes and .seg
+            # files leaking across the manager's lifetime.
+            self.tiered.delete_shuffle(shuffle_id, tenant=self.tenant)
+            if self.store is not None:  # shuffle files removed too
+                self.store.delete(shuffle_id)
 
     # --- durability (checkpoint / resume) -----------------------------
     def checkpoint_shuffle(self, handle: ShuffleHandle,
@@ -1013,7 +1013,6 @@ class ShuffleManager:
         w._records = records
         w._plan = plan
         self._writers[handle.shuffle_id] = w
-        self._plan_seconds[handle.shuffle_id] = 0.0
         self._registry.publish_map_output(handle.shuffle_id, plan.counts)
         log.info("shuffle %d resumed from checkpoint: %d records",
                  handle.shuffle_id, plan.total_records)
@@ -1349,6 +1348,7 @@ class ShuffleManager:
                 check_vma=not fast,   # pallas kernels defeat VMA typing
             ))
             self._sort_cache[key] = fn
+            self.metrics.counter("exchange.programs_built.sort").inc()
         return fn(out, totals)
 
     def __enter__(self) -> "ShuffleManager":

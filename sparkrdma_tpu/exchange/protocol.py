@@ -81,6 +81,7 @@ from sparkrdma_tpu.obs.stats import ExchangeRecord, ShuffleReadStats
 from sparkrdma_tpu.obs.timeline import NULL_TIMELINE, EventTimeline
 from sparkrdma_tpu.obs.watchdog import StallWatchdog
 from sparkrdma_tpu.runtime.mesh import mesh_interpret
+from sparkrdma_tpu.utils.profiling import annotate, device_phase, phase
 
 
 @dataclasses.dataclass(frozen=True)
@@ -99,6 +100,9 @@ class ShufflePlan:
     output stream (they appear once per sub-partition) — full-range
     reads (sort/aggregate/repartition) are unaffected; partition-range
     views refuse split plans.
+
+    ``plan_s`` is the wall-clock of the :meth:`ShuffleExchange.plan`
+    call that made the plan (0.0 for a plan restored from a checkpoint).
     """
 
     counts: np.ndarray          # int64 [mesh, num_parts * split_factor]
@@ -106,6 +110,7 @@ class ShufflePlan:
     out_capacity: int           # per-device compacted output capacity
     capacity: int               # slot capacity used for planning
     split_factor: int = 1
+    plan_s: float = 0.0
 
     @property
     def total_records(self) -> int:
@@ -159,13 +164,15 @@ def _make_count_fn(mesh: Mesh, axis_name: str, num_parts: int,
     """
 
     def local_counts(records):
-        pids = partitioner(records).astype(jnp.int32)
-        counts = histogram_pids(pids, num_parts)   # scatter-free
+        with jax.named_scope("sr_count"):
+            pids = partitioner(records).astype(jnp.int32)
+            counts = histogram_pids(pids, num_parts)   # scatter-free
         # all_gather -> replicated [mesh, P] so EVERY process can read the
         # table locally (multi-host: a sharded output would leave other
         # processes' rows non-addressable). This is the one-sided
         # metadata-table read of the reference, made collective.
-        return jax.lax.all_gather(counts, axis_name)
+        with jax.named_scope("sr_exchange"):
+            return jax.lax.all_gather(counts, axis_name)
 
     return jax.jit(
         shard_map(
@@ -274,8 +281,6 @@ class ShuffleExchange:
         # takes priority over the random ``fault_injection_rate``.
         self.fault_hook: Optional[Callable[[], bool]] = None
         self._fault_rng = np.random.default_rng(0xFA17)
-        #: wall-clock of the most recent plan() — folded into spans
-        self.last_plan_s = 0.0
         # graceful-degradation ladder, transport rung: when a ring /
         # hierarchical transport fails to construct and
         # conf.transport_fallback is on, the exchange permanently (per
@@ -295,6 +300,12 @@ class ShuffleExchange:
         """The transport actually in use (conf choice, or the sticky
         ``xla`` fallback after a transport degradation)."""
         return self._transport_override or self.conf.transport
+
+    def _built(self, kind: str) -> None:
+        """A program cache missed: one ``kind`` program is built, and
+        compiles (or loads from the persistent cache) on its first call
+        — so ``/metrics`` shows which step recompiled."""
+        self.metrics.counter(f"exchange.programs_built.{kind}").inc()
 
     def _get_buf(self, shape, sharding):
         """A device round buffer — through the tiered store when present
@@ -476,7 +487,20 @@ class ShuffleExchange:
         map-output table before issuing READs" step.
         """
         t0 = time.perf_counter()
-        self.timeline.begin("plan")
+        with phase("shuffle:plan", self.timeline) as at_end:
+            plan = self._plan(records, partitioner, num_parts, capacity)
+            plan_s = time.perf_counter() - t0
+            at_end.update(rounds=plan.num_rounds, capacity=plan.capacity,
+                          split=plan.split_factor)
+        self.metrics.counter("exchange.plans").inc()
+        self.metrics.histogram("exchange.plan_s").observe(plan_s)
+        return dataclasses.replace(plan, plan_s=plan_s)
+
+    def _plan(self, records, partitioner, num_parts, capacity
+              ) -> ShufflePlan:
+        """:meth:`plan`'s work: count passes (``shuffle:plan/count``:
+        program lookup, dispatch and the counts' ``device_get``) and the
+        host's geometry arithmetic (``shuffle:plan/geometry``)."""
         num_parts = num_parts or self.mesh_size
         explicit_capacity = capacity
         if num_parts % self.mesh_size:
@@ -489,36 +513,41 @@ class ShuffleExchange:
                    if self.conf.geometry_classes == "fine" else size_class)
 
         def measure(part_fn, parts):
-            key = (parts, getattr(part_fn, "cache_key", id(part_fn)))
-            fn = self._count_cache.get(key)
-            if fn is None:
-                fn = _make_count_fn(self.mesh, self.axis_name, parts,
-                                    part_fn)
-                self._count_cache[key] = fn
-            counts = np.asarray(jax.device_get(fn(records))).astype(np.int64)
-            if int(counts.sum()) != records.shape[1]:
-                # histogram_pids drops out-of-range ids (its documented
-                # precondition); catching the shortfall HERE — the one
-                # host-visible point every shuffle passes through — turns
-                # a buggy user partitioner into a loud error instead of
-                # quiet record loss downstream (round-3 advisor finding)
-                raise ValueError(
-                    f"partitioner produced out-of-range partition ids: "
-                    f"counted {int(counts.sum())} of {records.shape[1]} "
-                    f"records over {parts} partitions (ids must lie in "
-                    f"[0, num_parts))")
-            per_pair_max = int(counts.max(initial=0))
-            if explicit_capacity is not None:
-                cap = explicit_capacity
-            else:
-                # Auto-size the slot to the measured worst (src, dst)
-                # pair, capped by conf.slot_records (the maxAggBlock
-                # ceiling): a balanced shuffle then pads almost nothing,
-                # while skew streams in slot_records-sized rounds.
-                # Power-of-two classes bound the number of compiled
-                # geometries (same rule as the buffer pools).
-                cap = min(classer(max(1, per_pair_max)),
-                          self.conf.slot_records)
+            with annotate("shuffle:plan/count"):
+                key = (parts, getattr(part_fn, "cache_key", id(part_fn)))
+                fn = self._count_cache.get(key)
+                if fn is None:
+                    fn = _make_count_fn(self.mesh, self.axis_name, parts,
+                                        part_fn)
+                    self._count_cache[key] = fn
+                    self._built("count")
+                counts = np.asarray(
+                    jax.device_get(fn(records))).astype(np.int64)
+            with annotate("shuffle:plan/geometry"):
+                if int(counts.sum()) != records.shape[1]:
+                    # histogram_pids drops out-of-range ids (its
+                    # documented precondition); catching the shortfall
+                    # HERE — the one host-visible point every shuffle
+                    # passes through — turns a buggy user partitioner
+                    # into a loud error instead of quiet record loss
+                    # downstream (round-3 advisor finding)
+                    raise ValueError(
+                        f"partitioner produced out-of-range partition "
+                        f"ids: counted {int(counts.sum())} of "
+                        f"{records.shape[1]} records over {parts} "
+                        f"partitions (ids must lie in [0, num_parts))")
+                per_pair_max = int(counts.max(initial=0))
+                if explicit_capacity is not None:
+                    cap = explicit_capacity
+                else:
+                    # Auto-size the slot to the measured worst (src, dst)
+                    # pair, capped by conf.slot_records (the maxAggBlock
+                    # ceiling): a balanced shuffle then pads almost
+                    # nothing, while skew streams in slot_records-sized
+                    # rounds. Power-of-two classes bound the number of
+                    # compiled geometries (same rule as the buffer pools).
+                    cap = min(classer(max(1, per_pair_max)),
+                              self.conf.slot_records)
             return counts, cap, max(1, math.ceil(per_pair_max / cap))
 
         counts, capacity, num_rounds = measure(partitioner, num_parts)
@@ -540,18 +569,15 @@ class ShuffleExchange:
                 f"{self.conf.max_rounds} even after {split}-way partition "
                 "splitting; raise slot_records or max_rounds"
             )
-        # records received by device d = sum over sources of counts[:, p]
-        # for the partitions p owned by d (p % mesh == d)
-        owned = counts.sum(axis=0)  # [num_parts * split]
-        per_device_in = np.array(
-            [owned[d::self.mesh_size].sum() for d in range(self.mesh_size)]
-        )
-        out_capacity = classer(max(1, int(per_device_in.max())))
-        self.last_plan_s = time.perf_counter() - t0
-        self.metrics.counter("exchange.plans").inc()
-        self.metrics.histogram("exchange.plan_s").observe(self.last_plan_s)
-        self.timeline.end("plan", rounds=num_rounds, capacity=capacity,
-                          split=split)
+        with annotate("shuffle:plan/geometry"):
+            # records received by device d = sum over sources of
+            # counts[:, p] for the partitions p owned by d (p % mesh == d)
+            owned = counts.sum(axis=0)  # [num_parts * split]
+            per_device_in = np.array(
+                [owned[d::self.mesh_size].sum()
+                 for d in range(self.mesh_size)]
+            )
+            out_capacity = classer(max(1, int(per_device_in.max())))
         return ShufflePlan(
             counts=counts,
             num_rounds=num_rounds,
@@ -716,6 +742,7 @@ class ShuffleExchange:
     # ------------------------------------------------------------------
     # map-side front half (shared by both regimes)
     # ------------------------------------------------------------------
+    @device_phase("sr_bucket")
     def _map_side(self, records, partitioner, num_parts: int,
                   combine: bool, aggregator: str, float_payload: bool,
                   row_filter, kw_idx):
@@ -901,13 +928,13 @@ class ShuffleExchange:
                 # leading-axis concat, and the kernel DMAs row d of each
                 # round straight to device d with round r+1 posted while
                 # round r completes (double-buffered semaphore banks).
-                round_slots = [
-                    fill_round_slots_dest_major(
-                        sr, counts, offs, num_parts, mesh_size,
-                        capacity, r)[0]
-                    for r in range(num_rounds)
-                ]
-                slots = jnp.stack(round_slots)  # [R, mesh, ppd, W, C]
+                with jax.named_scope("sr_slots"):
+                    slots = jnp.stack([
+                        fill_round_slots_dest_major(
+                            sr, counts, offs, num_parts, mesh_size,
+                            capacity, r)[0]
+                        for r in range(num_rounds)
+                    ])                      # [R, mesh, ppd, W, C]
                 # the size exchange rides a one-column prefix lane of
                 # round 0's payload instead of a separate all_to_all
                 # serialized ahead of the data: lane[0, d, q] carries
@@ -918,9 +945,10 @@ class ShuffleExchange:
                     slots.dtype)
                 lane = lane.at[0, :, :, 0, 0].set(
                     dev_counts.astype(slots.dtype))
-                recv_all = ring_ex(
-                    jnp.concatenate([lane, slots], axis=4)
-                )                           # [R, mesh, ppd, W, C+1]
+                with jax.named_scope("sr_exchange"):
+                    recv_all = ring_ex(
+                        jnp.concatenate([lane, slots], axis=4)
+                    )                       # [R, mesh, ppd, W, C+1]
                 # recv_all[0, s, q, 0, 0] = sender s's dev_counts[my, q]
                 # — exactly all_to_all(dev_counts)[s, q]
                 incoming = recv_all[0, :, :, 0, 0].astype(jnp.int32)
@@ -932,26 +960,30 @@ class ShuffleExchange:
                     ppd * mesh_size * num_rounds * capacity,
                 )
             else:
-                incoming = lax.all_to_all(
-                    dev_counts, ax, split_axis=0, concat_axis=0,
-                    tiled=True)                             # [mesh, ppd]
+                with jax.named_scope("sr_exchange"):
+                    incoming = lax.all_to_all(
+                        dev_counts, ax, split_axis=0, concat_axis=0,
+                        tiled=True)                         # [mesh, ppd]
 
                 # --- data rounds --------------------------------------
                 recv_rounds = []
                 for r in range(num_rounds):
-                    slots, _ = fill_round_slots(
-                        sr, counts, offs, num_parts, capacity, r
-                    )                                       # [W, P, C]
-                    # group per destination device: [mesh, ppd, W, C]
-                    # (partition p = q*mesh + d lives on device d,
-                    # local q)
-                    slots = slots.reshape(w_eff, ppd, mesh_size,
-                                          capacity).transpose(2, 1, 0, 3)
+                    with jax.named_scope("sr_slots"):
+                        slots, _ = fill_round_slots(
+                            sr, counts, offs, num_parts, capacity, r
+                        )                                   # [W, P, C]
+                        # group per destination device: [mesh, ppd, W,
+                        # C] (partition p = q*mesh + d lives on device
+                        # d, local q)
+                        slots = slots.reshape(
+                            w_eff, ppd, mesh_size,
+                            capacity).transpose(2, 1, 0, 3)
                     # dest-major [mesh, ppd, W, C]: the configured
                     # transport moves row d to device d (xla:
                     # lax.all_to_all; pallas_ring: one-sided remote-DMA
                     # descriptors)
-                    recv = data_a2a(slots)              # [mesh, ppd, W, C]
+                    with jax.named_scope("sr_exchange"):
+                        recv = data_a2a(slots)          # [mesh, ppd, W, C]
                     recv_rounds.append(recv)
 
                 # data[s, q, r, :, c] = round r's c-th record from
@@ -1035,8 +1067,10 @@ class ShuffleExchange:
                 float_payload, row_filter, kw_idx)
             dev_counts = _device_partition_counts(
                 counts, num_parts, mesh_size, ax)
-            incoming = lax.all_to_all(
-                dev_counts, ax, split_axis=0, concat_axis=0, tiled=True)
+            with jax.named_scope("sr_exchange"):
+                incoming = lax.all_to_all(
+                    dev_counts, ax, split_axis=0, concat_axis=0,
+                    tiled=True)
             total = jnp.sum(incoming).astype(jnp.int32)
             return sr, counts, offs, incoming[None], total[None]
 
@@ -1074,20 +1108,27 @@ class ShuffleExchange:
                 # chunk moved by one double-buffered kernel. No counts
                 # lane here — the streaming regime's prep already did
                 # the size exchange.
-                chunk = ring_ex(jnp.stack([
-                    fill_round_slots_dest_major(
-                        sr, counts, offs, num_parts, mesh_size,
-                        capacity, r0[0] + j)[0]
-                    for j in range(rounds_per)
-                ]))                       # [rounds_per, mesh, ppd, W, C]
+                with jax.named_scope("sr_slots"):
+                    slots = jnp.stack([
+                        fill_round_slots_dest_major(
+                            sr, counts, offs, num_parts, mesh_size,
+                            capacity, r0[0] + j)[0]
+                        for j in range(rounds_per)
+                    ])
+                with jax.named_scope("sr_exchange"):
+                    chunk = ring_ex(slots)  # [rounds_per, mesh, ppd, W, C]
             else:
                 recvs = []
                 for j in range(rounds_per):
-                    slots, _ = fill_round_slots(
-                        sr, counts, offs, num_parts, capacity, r0[0] + j)
-                    slots = slots.reshape(record_words, ppd, mesh_size,
-                                          capacity).transpose(2, 1, 0, 3)
-                    recvs.append(data_a2a(slots))   # [mesh, ppd, W, C]
+                    with jax.named_scope("sr_slots"):
+                        slots, _ = fill_round_slots(
+                            sr, counts, offs, num_parts, capacity,
+                            r0[0] + j)
+                        slots = slots.reshape(
+                            record_words, ppd, mesh_size,
+                            capacity).transpose(2, 1, 0, 3)
+                    with jax.named_scope("sr_exchange"):
+                        recvs.append(data_a2a(slots))  # [mesh, ppd, W, C]
                 chunk = jnp.stack(recvs,
                                   axis=0)  # [rounds_per, mesh, ppd, W, C]
             return lax.dynamic_update_slice(
@@ -1254,6 +1295,7 @@ class ShuffleExchange:
             if fn is None:
                 fn = builder()
                 self._exec_cache[key] = fn
+                self._built(key[0])
             return fn
 
         from sparkrdma_tpu.exchange.ring import derive_collective_id
@@ -1340,12 +1382,6 @@ class ShuffleExchange:
             r0 = jnp.full((1,), j * F, jnp.int32)
             recv = chunk_fn(sr, counts, offs, r0, recv_buf)
             tl.event("chunk:dispatch", chunk=j, rounds=F)
-            if self._ring_fused_active():
-                # structural annotations (see exchange()): the chunk's F
-                # rounds run inside one fused kernel
-                for jr in range(F):
-                    tl.begin("ring:round", round=j * F + jr)
-                    tl.end("ring:round", round=j * F + jr)
             fold = cached(
                 ("fold", num_parts, cap, F, total_rounds,
                  plan.out_capacity, w_eff, j == 0),
@@ -1539,6 +1575,38 @@ class ShuffleExchange:
                 shuffle_id=shuffle_id, combine=use_combine,
                 row_filter=row_filter, keep_words=keep_words)
         w = records.shape[0]
+        donate = self.pool is not None
+        with annotate("shuffle:exchange/program"):
+            fn, key = self._exec_program(
+                records, partitioner, plan, num_parts, sort_key_words,
+                aggregator, float_payload, use_combine, row_filter,
+                keep_words, donate)
+        self.last_dispatches = 1
+        self.metrics.counter("exchange.dispatches").inc()
+        # the phase's timeline pair closes even when the dispatch raises,
+        # so the span's timeline stays balanced across retry attempts
+        with phase("shuffle:exchange/dispatch", self.timeline,
+                   rounds=plan.num_rounds):
+            if donate:
+                okey = (shuffle_id, key)
+                sharding = NamedSharding(self.mesh, P(None, self.axis_name))
+                with annotate("shuffle:exchange/buffers"):
+                    prev = self._out_prev.pop(okey, None)
+                    if prev is not None:
+                        self._put_buf(prev[0], prev[1])
+                    buf = self._get_buf(
+                        (w, self.mesh_size * plan.out_capacity), sharding)
+                out, totals, incoming = fn(records, buf)
+                self._out_prev[okey] = (out, sharding)
+                return out, totals, incoming
+            return fn(records)
+
+    def _exec_program(self, records, partitioner, plan, num_parts,
+                      sort_key_words, aggregator, float_payload,
+                      use_combine, row_filter, keep_words, donate):
+        """The fused regime's program for this exchange, from the cache
+        or built: ``(fn, cache key)``."""
+        w = records.shape[0]
         # every device's output exactly full -> the fused sort can drop
         # its validity lead operand (static fact from the plan's counts;
         # any pre-exchange reduction shrinks totals below the plan, so
@@ -1559,7 +1627,6 @@ class ShuffleExchange:
                w, sort_key_words, aggregator, float_payload, tight,
                use_combine, fkey, keep_words,
                getattr(partitioner, "cache_key", id(partitioner)))
-        donate = self.pool is not None
         fn = self._exec_cache.get(key)
         if fn is None:
             from sparkrdma_tpu.exchange.ring import derive_collective_id
@@ -1573,34 +1640,8 @@ class ShuffleExchange:
                                   row_filter=row_filter,
                                   keep_words=keep_words)
             self._exec_cache[key] = fn
-        self.last_dispatches = 1
-        self.metrics.counter("exchange.dispatches").inc()
-        self.timeline.begin("exchange:fused", rounds=plan.num_rounds)
-        if self._ring_fused_active():
-            # structural annotations: the rounds run INSIDE one kernel
-            # (that is the point), so per-round host spans cannot bracket
-            # real device time — they record the round structure the
-            # fused dispatch carries for trace tooling.
-            for r in range(plan.num_rounds):
-                self.timeline.begin("ring:round", round=r)
-                self.timeline.end("ring:round", round=r)
-        try:
-            if donate:
-                okey = (shuffle_id, key)
-                sharding = NamedSharding(self.mesh, P(None, self.axis_name))
-                prev = self._out_prev.pop(okey, None)
-                if prev is not None:
-                    self._put_buf(prev[0], prev[1])
-                buf = self._get_buf(
-                    (w, self.mesh_size * plan.out_capacity), sharding)
-                out, totals, incoming = fn(records, buf)
-                self._out_prev[okey] = (out, sharding)
-                return out, totals, incoming
-            return fn(records)
-        finally:
-            # closes even when the dispatch raises, so the span's
-            # timeline stays balanced across retry attempts
-            self.timeline.end("exchange:fused")
+            self._built("exec")
+        return fn, key
 
     def release_shuffle(self, shuffle_id: int) -> None:
         """Return a shuffle's recycled output buffers to the pool.
@@ -1660,7 +1701,7 @@ class ShuffleExchange:
         if self.stats.enabled:
             self.stats.add(ExchangeRecord(
                 shuffle_id=shuffle_id,
-                plan_s=self.last_plan_s,
+                plan_s=plan.plan_s,
                 exec_s=t.elapsed,
                 total_records=plan.total_records,
                 record_bytes=records.shape[0] * 4,
@@ -1681,7 +1722,7 @@ class ShuffleExchange:
                 dispatches=self.last_dispatches,
                 records=plan.total_records,
                 record_bytes=records.shape[0] * 4,
-                plan_s=self.last_plan_s,
+                plan_s=plan.plan_s,
                 exchange_s=t.elapsed,
                 sort_s=0.0,
                 per_peer_records=[int(c) for c in plan.counts.sum(axis=1)],

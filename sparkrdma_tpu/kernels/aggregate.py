@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from sparkrdma_tpu.kernels.sort import lexsort_cols
+from sparkrdma_tpu.utils.profiling import device_phase
 
 
 def _segmented_scan(vals: jax.Array, first: jax.Array, op) -> jax.Array:
@@ -59,6 +60,7 @@ def _segmented_scan(vals: jax.Array, first: jax.Array, op) -> jax.Array:
     return out
 
 
+@device_phase("sr_combine")
 def combine_by_key_cols(
     cols: jax.Array,
     valid: jax.Array,
@@ -154,6 +156,7 @@ def combine_by_key_cols(
     return out, num_unique
 
 
+@device_phase("sr_combine")
 def map_side_combine_cols(
     records: jax.Array,
     part_ids: jax.Array,

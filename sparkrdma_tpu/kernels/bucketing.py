@@ -38,6 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from sparkrdma_tpu.utils.profiling import device_phase
+
 #: Above this many serially-dependent copies, emit a device loop instead of
 #: unrolling — keeps program size O(1) in partition/segment count.
 _UNROLL_LIMIT = 16
@@ -166,6 +168,7 @@ def bucket_sorted_counts(
     return counts, offsets
 
 
+@device_phase("sr_slots")
 def fill_round_slots(
     bucketed: jax.Array,
     counts: jax.Array,
@@ -215,6 +218,7 @@ def fill_round_slots(
     return slots, send_counts.astype(jnp.int32)
 
 
+@device_phase("sr_slots")
 def fill_round_slots_dest_major(
     bucketed: jax.Array,
     counts: jax.Array,
@@ -271,6 +275,7 @@ def fill_round_slots_dest_major(
     return slots, send_counts.astype(jnp.int32)
 
 
+@device_phase("sr_compact")
 def compact_segments(
     stream: jax.Array, seg_counts: jax.Array, out_capacity: int
 ) -> Tuple[jax.Array, jax.Array]:

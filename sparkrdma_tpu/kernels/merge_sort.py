@@ -65,6 +65,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from sparkrdma_tpu.utils.profiling import device_phase
+
 _FULL = np.uint32(0xFFFFFFFF)   # numpy scalar: kernels may close over it
 
 
@@ -423,6 +425,7 @@ def supports_fast_sort(n: int, run: int = 1 << 15) -> bool:
     return n >= 2 * run and (n & (n - 1)) == 0
 
 
+@device_phase("sr_sort_keys")
 def merge_sort_cols(
     cols: jax.Array,
     valid: Optional[jax.Array] = None,

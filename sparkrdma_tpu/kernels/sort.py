@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax import enable_x64, lax
 
+from sparkrdma_tpu.utils.profiling import device_phase
+
 
 def _sort_rows(records: jax.Array, num_keys: int,
                lead_keys: Tuple[jax.Array, ...] = ()) -> jax.Array:
@@ -89,6 +91,7 @@ def lexsort_records(
     return _sort_rows(records, key_words, lead_keys=lead)
 
 
+@device_phase("sr_sort_keys")
 def lexsort_cols(
     cols: jax.Array, key_words: int, valid: jax.Array | None = None,
     stable: bool = True
@@ -121,6 +124,7 @@ def _unpack_u64(p: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return two[:, 1], two[:, 0]
 
 
+@device_phase("sr_sort_keys")
 def packed_lexsort_cols(
     cols: jax.Array, key_words: int, valid: jax.Array | None = None,
     stable: bool = False
@@ -187,7 +191,9 @@ def packed_partition_cols(
     operands instead of lead + W.
     """
     cols2 = jnp.concatenate([lead[None].astype(jnp.uint32), cols])
-    out = packed_lexsort_cols(cols2, 1, stable=stable)
+    # the sort outside ``sr_sort_keys``: ordering by a computed lead is
+    # the caller's phase (map-side bucketing, a compaction)
+    out = packed_lexsort_cols.__wrapped__(cols2, 1, stable=stable)
     return out[0], out[1:]
 
 

@@ -39,6 +39,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from sparkrdma_tpu.utils.profiling import device_phase
+
 #: Chunk length for the gather of payload rows. Bounds the per-gather
 #: index extent so XLA's TPU window bookkeeping stays within uint32
 #: (the flat 16M-row gather aborts the compiler) while keeping the
@@ -46,6 +48,7 @@ from jax import lax
 _TAKE_CHUNK = 1 << 20
 
 
+@device_phase("sr_sort_keys")
 def sort_perm(
     cols: jax.Array, key_words: int, valid: Optional[jax.Array] = None
 ) -> Tuple[jax.Array, jax.Array]:
@@ -109,26 +112,32 @@ def sort_wide_cols(
     (``ShuffleConf.wide_sort_ride_words``).
 
     Drop-in for :func:`~sparkrdma_tpu.kernels.sort.lexsort_cols` (same
-    contract: stable, padding to the tail) for wide records.
+    contract: stable, padding to the tail) for wide records. In a
+    trace the key sort is the ``sr_sort_keys`` phase and the payload
+    placement ``sr_sort_gather``; :func:`apply_perm` itself carries no
+    phase, so the map-side bucket and the compactions that place rows
+    with it keep the time in their own.
     """
     w, n = cols.shape
     ride = max(0, min(ride_words, w - key_words))
-    idx = lax.iota(jnp.int32, n)
-    lead = () if valid is None else ((~valid).astype(jnp.uint8),)
-    operands = lead + tuple(cols[i] for i in range(key_words + ride)) \
-        + (idx,)
-    out = lax.sort(operands, num_keys=len(lead) + key_words,
-                   is_stable=True)
-    ridden = jnp.stack(out[len(lead):-1])          # keys + ridden payload
-    perm = out[-1]
+    with jax.named_scope("sr_sort_keys"):
+        idx = lax.iota(jnp.int32, n)
+        lead = () if valid is None else ((~valid).astype(jnp.uint8),)
+        operands = lead + tuple(cols[i] for i in range(key_words + ride)) \
+            + (idx,)
+        out = lax.sort(operands, num_keys=len(lead) + key_words,
+                       is_stable=True)
+        ridden = jnp.stack(out[len(lead):-1])      # keys + ridden payload
+        perm = out[-1]
     if ride == w - key_words:
         return ridden
-    payload = cols[key_words + ride:]              # [W-kw-ride, N]
-    # gather along the RECORD axis: rows-major [N, *] so each index
-    # fetches one contiguous record slice; the transposes are plain
-    # streaming passes that XLA fuses around the gather
-    placed = apply_perm(payload.T, perm).T
-    return jnp.concatenate([ridden, placed], axis=0)
+    with jax.named_scope("sr_sort_gather"):
+        payload = cols[key_words + ride:]          # [W-kw-ride, N]
+        # gather along the RECORD axis: rows-major [N, *] so each index
+        # fetches one contiguous record slice; the transposes are plain
+        # streaming passes that XLA fuses around the gather
+        placed = apply_perm(payload.T, perm).T
+        return jnp.concatenate([ridden, placed], axis=0)
 
 
 __all__ = ["sort_wide_cols", "sort_perm", "apply_perm"]

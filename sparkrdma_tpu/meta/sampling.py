@@ -21,6 +21,7 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from sparkrdma_tpu.kernels.sort import lexsort_records
+from sparkrdma_tpu.utils.profiling import annotate, device_phase
 
 
 def make_sampler(mesh: Mesh, axis_name: str, key_words: int,
@@ -38,6 +39,7 @@ def make_sampler(mesh: Mesh, axis_name: str, key_words: int,
     Returns ``uint32[mesh * samples_per_device, key_words]`` replicated.
     """
 
+    @device_phase("sr_sample")
     def local_sample(records):
         # records: columnar [W, n_local]
         n = records.shape[1]
@@ -67,15 +69,16 @@ def compute_splitters(samples: np.ndarray, num_parts: int) -> np.ndarray:
     Returns ``uint32[num_parts - 1, key_words]`` ascending — the input to
     :func:`sparkrdma_tpu.exchange.partitioners.range_partitioner`.
     """
-    samples = np.asarray(samples)
-    if samples.ndim != 2:
-        raise ValueError("samples must be [n, key_words]")
-    n, kw = samples.shape
-    if n == 0 or num_parts < 2:
-        return np.zeros((max(0, num_parts - 1), kw), dtype=np.uint32)
-    srt = np.asarray(lexsort_records(jnp.asarray(samples), kw))
-    idx = (np.arange(1, num_parts) * n) // num_parts
-    return srt[idx].astype(np.uint32)
+    with annotate("shuffle:splitters"):
+        samples = np.asarray(samples)
+        if samples.ndim != 2:
+            raise ValueError("samples must be [n, key_words]")
+        n, kw = samples.shape
+        if n == 0 or num_parts < 2:
+            return np.zeros((max(0, num_parts - 1), kw), dtype=np.uint32)
+        srt = np.asarray(lexsort_records(jnp.asarray(samples), kw))
+        idx = (np.arange(1, num_parts) * n) // num_parts
+        return srt[idx].astype(np.uint32)
 
 
 __all__ = ["make_sampler", "compute_splitters"]

@@ -50,7 +50,7 @@ VERDICTS = frozenset({
 #: mapped here (pool acquires, counter tracks, fault markers) are
 #: structural and charge whatever phase encloses them.
 PHASE_OF = {
-    "plan": "plan",
+    "shuffle:plan": "plan",
     "combine:gate": "combine",
     "serde:encode": "encode",
     "serde:h2d": "h2d",
@@ -58,8 +58,7 @@ PHASE_OF = {
     "serde:decode": "decode",
     "stream:prep": "dispatch",
     "chunk": "dispatch",
-    "ring:round": "dispatch",
-    "exchange:fused": "dispatch",
+    "shuffle:exchange/dispatch": "dispatch",
     "queue:block": "queue_block",
     "fold": "fold",
     "spill": "spill",

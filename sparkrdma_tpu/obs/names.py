@@ -128,6 +128,7 @@ TIMELINE_TRACKS = frozenset({
 #: still have a matching emission site.
 WILDCARDS = frozenset({
     "faults.*",
+    "exchange.programs_built.*",
     "degrade.*",
     "recover.*",
     "serde.*_bytes",

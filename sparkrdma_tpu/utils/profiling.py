@@ -5,41 +5,19 @@ histogram printed to the executor log (RdmaShuffleReaderStats, behind
 ``spark.shuffle.rdma.collectShuffleReadStats``) plus Spark's own metrics.
 The TPU build keeps the histogram idea in :mod:`sparkrdma_tpu.utils.stats`
 and adds what a compiled SPMD runtime can offer that a JVM plugin cannot:
-XLA device traces. ``trace`` wraps a region in a ``jax.profiler`` trace
-(viewable in TensorBoard/XProf/Perfetto); ``annotate`` names sub-regions
-so exchange phases (plan / exchange / sort) are attributable inside the
-trace timeline.
+XLA device traces. ``annotate`` and ``phase`` name host regions
+(``shuffle:plan``, ``shuffle:exchange/dispatch``...) on the profiler's
+own clock, so each idle gap of the device in a trace is attributable to
+what the host was doing; the compiled programs name their device phases
+with ``jax.named_scope("sr_*")``. Whoever profiles starts the profiler
+(``jax.profiler.start_trace``); these hooks cost nothing when it is off.
 """
 
 from __future__ import annotations
 
 import contextlib
-import logging
-import os
-from typing import Iterator, Optional
-
-log = logging.getLogger("sparkrdma_tpu.profiling")
-
-
-@contextlib.contextmanager
-def trace(log_dir: str, create_perfetto_link: bool = False) -> Iterator[None]:
-    """Capture a jax profiler trace of the enclosed region into ``log_dir``.
-
-    Usage::
-
-        with profiling.trace("/tmp/shuffle-trace"):
-            reader.read()
-    """
-    import jax
-
-    os.makedirs(log_dir, exist_ok=True)
-    jax.profiler.start_trace(log_dir,
-                             create_perfetto_link=create_perfetto_link)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-        log.info("profiler trace written to %s", log_dir)
+import functools
+from typing import Callable, Dict, Iterator
 
 
 def annotate(name: str):
@@ -60,14 +38,45 @@ def annotate_span(phase: str, span_id: int = 0):
     return annotate(f"{phase}#s{span_id}" if span_id else phase)
 
 
+def device_phase(name: str) -> Callable[[Callable], Callable]:
+    """Decorator: trace the function under ``jax.named_scope(name)``, so
+    every op it emits into a compiled program carries ``name`` in its
+    metadata (the ``tf_op`` stat of a profiler trace). Op metadata only:
+    the compiled program runs the same. A fresh scope per call, since a
+    scope object keeps state while entered."""
+
+    def wrap(fn: Callable) -> Callable:
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            import jax
+
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+
+        return scoped
+
+    return wrap
+
+
 @contextlib.contextmanager
-def maybe_trace(log_dir: Optional[str]) -> Iterator[None]:
-    """``trace`` when a directory is configured, no-op otherwise."""
-    if log_dir:
-        with trace(log_dir):
-            yield
-    else:
-        yield
+def phase(name: str, timeline=None, **extras) -> Iterator[Dict]:
+    """One named phase of the host code, named once for both records:
+    a TraceAnnotation ``name`` in the profiler's trace and, when
+    ``timeline`` (an :class:`~sparkrdma_tpu.obs.timeline.EventTimeline`)
+    is enabled, its begin/end pair under the same name for the journal.
+
+    ``extras`` ride the begin event; the dict yielded collects extras
+    for the end event (known only once the phase has run)."""
+    at_end: Dict = {}
+    with annotate(name):
+        if timeline is None or not timeline.enabled:
+            yield at_end
+            return
+        timeline.begin(name, **extras)
+        try:
+            yield at_end
+        finally:
+            timeline.end(name, **at_end)
 
 
-__all__ = ["trace", "annotate", "maybe_trace"]
+__all__ = ["annotate", "annotate_span", "device_phase", "phase"]

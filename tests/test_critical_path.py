@@ -47,7 +47,8 @@ def total(phase_s):
 
 class TestAttribute:
     def test_single_interval_plus_other(self):
-        ph = cp.attribute([B(0.0, "plan"), E(0.1, "plan")], wall_s=0.3)
+        ph = cp.attribute([B(0.0, "shuffle:plan"), E(0.1, "shuffle:plan")],
+                          wall_s=0.3)
         assert ph["plan"] == pytest.approx(0.1)
         assert ph["other"] == pytest.approx(0.2)
         assert total(ph) == pytest.approx(0.3)
@@ -94,7 +95,7 @@ class TestAttribute:
         assert ph["dispatch"] == pytest.approx(0.25, abs=1e-5)
 
     def test_unclosed_interval_counts_self_time_only(self):
-        events = [B(0.0, "plan"), I(0.02, "stall")]   # plan never ends
+        events = [B(0.0, "shuffle:plan"), I(0.02, "stall")]   # plan never ends
         ph = cp.attribute(events, wall_s=0.1)
         assert ph["plan"] == pytest.approx(0.02)
         assert ph["other"] == pytest.approx(0.08)

@@ -346,6 +346,16 @@ class TestLeaseExpiry:
             x = _records(conf, mesh, 8, seed=4)
             c = _client(svc, "lapsed")
             c.hello()
+            # beat through the set-up calls, so a slow (loaded) run
+            # cannot outlast the lease before the checkpoint is adopted;
+            # a second connection under the same client id, since one
+            # client's beats wait behind its own long calls; its request
+            # ids start far above c's, or the server's replay cache would
+            # answer c's calls with the keeper's cached replies
+            keeper = _client(svc, "lapsed")
+            keeper._next_req = 1 << 20
+            keeper.hello()
+            keeper.start_heartbeat()
             s = c.open_session("blue")
             c.admit("blue", 1)
             c.register_shuffle(s, 706, mesh)
@@ -357,6 +367,8 @@ class TestLeaseExpiry:
             assert svc.stats()["sessions"] == 1
             u = svc.usage_by_tenant()["blue"]
             assert u["host"] + u["disk"] >= 1
+            # from here on no heartbeat: the lease lapses
+            keeper.stop_heartbeat()
             deadline = time.monotonic() + 5.0
             while (svc.stats()["sessions"] and
                    time.monotonic() < deadline):

@@ -1,0 +1,190 @@
+"""The shuffle names its phases where the work happens.
+
+- Device: every compiled phase carries its ``sr_*`` name scope into the
+  program's op metadata (JAX's name stack), which a profiler trace then
+  holds as each op's ``tf_op`` stat.
+- Host: the ``shuffle:*`` TraceAnnotations of one job, nested as the
+  work is, in a real profiler trace.
+- Programs: a cache miss counts ``exchange.programs_built.<kind>``.
+"""
+
+import re
+
+import numpy as np
+import pytest
+
+import jax
+
+from sparkrdma_tpu import MeshRuntime, ShuffleConf
+from sparkrdma_tpu.api.shuffle_manager import ShuffleManager
+from sparkrdma_tpu.exchange.partitioners import (modulo_partitioner,
+                                                 range_partitioner)
+from sparkrdma_tpu.exchange.protocol import ShuffleExchange, _make_count_fn
+from sparkrdma_tpu.meta.sampling import compute_splitters, make_sampler
+
+N_LOCAL = 16
+KW = 2
+#: payload wide enough for the key+index sort and the gather placement
+WIDE = dict(key_words=KW, val_words=2, wide_sort_min_payload=1,
+            pack_sort_min_payload=0, wide_sort_ride_words=0)
+
+
+@pytest.fixture(scope="module")
+def lowered(runtime):
+    """Debug-info text of each program a shuffle compiles, by name."""
+    mesh, ax = runtime.mesh, runtime.axis_name
+    parts = runtime.num_partitions
+    rows = np.random.default_rng(3).integers(
+        0, 2**32, size=(parts * N_LOCAL, 4), dtype=np.uint32)
+    x = runtime.shard_records(rows)
+    ex = ShuffleExchange(mesh, ax, ShuffleConf(**WIDE))
+    part = modulo_partitioner(parts)
+    cap, out_cap = N_LOCAL, parts * N_LOCAL
+
+    def text(fn, *args):
+        return fn.lower(*args).as_text(debug_info=True)
+
+    return {
+        "sample": text(make_sampler(mesh, ax, KW, 4), x),
+        "count": text(_make_count_fn(mesh, ax, parts, part), x),
+        "sort": text(ex._build_exec(parts, cap, 1, out_cap, 4, part,
+                                    sort_key_words=KW), x),
+        "combine": text(ex._build_exec(parts, cap, 1, out_cap, 4, part,
+                                       aggregator="sum", combine=True), x),
+    }
+
+
+@pytest.mark.parametrize("scope,program", [
+    ("sr_sample", "sample"),
+    ("sr_count", "count"),
+    ("sr_bucket", "sort"),
+    ("sr_slots", "sort"),
+    ("sr_exchange", "sort"),
+    ("sr_compact", "sort"),
+    ("sr_sort_keys", "sort"),
+    ("sr_sort_gather", "sort"),
+    ("sr_combine", "combine"),
+])
+def test_program_carries_phase_scope(lowered, scope, program):
+    # a location's name stack starts the string or follows a parent
+    assert re.search(f'["/]{scope}/', lowered[program])
+
+
+def test_map_side_gather_stays_in_bucket_phase(lowered):
+    """The wide map-side bucket places its payload by a gather too; it
+    is bucketing, so no op under ``sr_bucket`` names the reduce-side
+    gather."""
+    assert re.search('["/]sr_bucket/', lowered["sort"])
+    assert "sr_bucket/sr_sort_gather" not in lowered["sort"]
+    assert "sr_bucket/sr_sort_keys" not in lowered["sort"]
+
+
+def _job(m, sid, rows, splitters=None, end_partition=None):
+    """One whole job; ``end_partition`` reads a partition range, which
+    the manager key-sorts in a program of its own."""
+    rt = m.runtime
+    x = rt.shard_records(rows)
+    parts = rt.num_partitions
+    if splitters is None:
+        part = modulo_partitioner(parts)
+    else:
+        part = range_partitioner(splitters, KW)
+    h = m.register_shuffle(sid, parts, part)
+    m.get_writer(h).write(x).stop(True)
+    out, totals = m.get_reader(h, end_partition=end_partition,
+                               key_ordering=end_partition is not None
+                               ).read()
+    jax.block_until_ready((out, totals))
+    m.unregister_shuffle(sid)
+    return out, totals
+
+
+def _built(m, kind):
+    return m.metrics.counter(f"exchange.programs_built.{kind}").value
+
+
+def _rows(seed, parts):
+    return np.random.default_rng(seed).integers(
+        0, 2**32, size=(parts * N_LOCAL, 4), dtype=np.uint32)
+
+
+def test_new_splitters_build_new_programs(tmp_path):
+    conf = ShuffleConf(slot_records=64,
+                       metrics_sink=str(tmp_path / "j.jsonl"))
+    with ShuffleManager(MeshRuntime(conf), conf) as m:
+        parts = m.runtime.num_partitions
+        a = compute_splitters(_rows(1, parts)[:64, :KW], parts)
+        b = compute_splitters(_rows(2, parts)[:64, :KW], parts)
+        _job(m, 1, _rows(5, parts), a)
+        first = (_built(m, "count"), _built(m, "exec"))
+        assert first == (1, 1)
+        _job(m, 2, _rows(6, parts), a)      # same splitters: cache hit
+        assert (_built(m, "count"), _built(m, "exec")) == first
+        _job(m, 3, _rows(7, parts), b)      # new splitters: both miss
+        assert (_built(m, "count"), _built(m, "exec")) == (2, 2)
+
+
+def test_ranged_sorted_read_counts_sort_program(tmp_path):
+    conf = ShuffleConf(slot_records=64,
+                       metrics_sink=str(tmp_path / "j.jsonl"))
+    with ShuffleManager(MeshRuntime(conf), conf) as m:
+        parts = m.runtime.num_partitions
+        _job(m, 1, _rows(8, parts), end_partition=parts - 1)
+        _job(m, 2, _rows(9, parts), end_partition=parts - 1)
+        assert _built(m, "sort") == 1
+
+
+def test_streaming_regime_counts_its_programs(tmp_path):
+    conf = ShuffleConf(slot_records=8, max_rounds_in_flight=1,
+                       queue_depth=2, metrics_sink=str(tmp_path / "j.jsonl"))
+    with ShuffleManager(MeshRuntime(conf), conf) as m:
+        parts = m.runtime.num_partitions
+        rows = _rows(10, parts)
+        rows[:, 0] = 0                       # one hot partition: rounds > 1
+        _job(m, 1, rows)
+        for kind in ("prep", "chunk", "tail"):
+            assert _built(m, kind) == 1, kind
+        assert _built(m, "fold") >= 1
+        assert _built(m, "exec") == 0
+
+
+#: host spans of one range-partitioned job, and the span each one must
+#: lie inside
+NESTED = {
+    "shuffle:plan/count": "shuffle:plan",
+    "shuffle:plan/geometry": "shuffle:plan",
+    "shuffle:exchange/program": "shuffle:exchange",
+    "shuffle:exchange/dispatch": "shuffle:exchange",
+    "shuffle:exchange/buffers": "shuffle:exchange/dispatch",
+}
+TOP = ("shuffle:splitters", "shuffle:plan", "shuffle:plan/publish",
+       "shuffle:exchange", "shuffle:read/barrier", "shuffle:unregister")
+
+
+def test_profiler_trace_holds_host_phases(tmp_path):
+    from perfbench.trace_reduce import find_xplane, host_spans
+
+    conf = ShuffleConf(slot_records=64)
+    with ShuffleManager(MeshRuntime(conf), conf) as m:
+        parts = m.runtime.num_partitions
+        rows = _rows(11, parts)
+        x = m.runtime.shard_records(rows)
+        sampler = make_sampler(m.runtime.mesh, m.runtime.axis_name, KW, 8)
+        _job(m, 1, rows, compute_splitters(np.asarray(sampler(x)), parts))
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            _job(m, 2, rows, compute_splitters(np.asarray(sampler(x)),
+                                               parts))
+        finally:
+            jax.profiler.stop_trace()
+    spans = host_spans(jax.profiler.ProfileData.from_file(
+        find_xplane(str(tmp_path))))
+    by_name = {}
+    for name, s, e in spans:
+        by_name.setdefault(name, []).append((s, e))
+    for name in TOP + tuple(NESTED):
+        assert name in by_name, name
+    for child, parent in NESTED.items():
+        for s, e in by_name[child]:
+            assert any(ps <= s and e <= pe for ps, pe in by_name[parent]), \
+                (child, parent)

@@ -328,7 +328,8 @@ class TestStreamingTimelineE2E:
         _run_streaming_read(conf, rng, shuffle_id=82)
         (span,) = read_journal(str(sink))
         names = [e["name"] for e in span.events]
-        assert "plan" in names and "exchange:fused" in names
+        assert "shuffle:plan" in names
+        assert "shuffle:exchange/dispatch" in names
         assert "chunk:dispatch" not in names
 
 
