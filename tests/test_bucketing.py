@@ -1,10 +1,12 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from sparkrdma_tpu.kernels import (bucket_records, compact_segments,
                                    fill_round_slots,
                                    fill_round_slots_dest_major)
+from sparkrdma_tpu.kernels.bucketing import histogram_pids
 
 
 def _cols(rows):
@@ -127,23 +129,45 @@ def test_compact_segments_program_size_flat_in_segments(rng):
     assert l256 < 1.5 * l64, (l64, l256)
 
 
-def test_histogram_pids_matches_bincount(rng):
-    """Both paths (comparison-sum for small P, searchsorted for large P
-    or pre-sorted ids) must match numpy bincount for in-range pids."""
-    from sparkrdma_tpu.kernels.bucketing import histogram_pids
+@pytest.mark.parametrize("p,n,hot", [
+    (4, 5000, None), (32, 5000, None), (64, 5000, None), (300, 5000, None),
+    (8, 100, 3),            # empty partitions + everything in one bucket
+    # the outer-product form (P > 32, ids not sorted): N of 1, N around
+    # the 1024-id tile of a TPU vector, P off the 16-wide grid
+    (33, 1, None), (256, 1, None), (64, 1023, None), (256, 1024, None),
+    (300, 1025, None), (1024, 5000, None),
+    (33, 700, 32), (256, 5000, 0), (256, 5000, 255), (1024, 1025, 1023),
+])
+def test_histogram_pids_matches_bincount(rng, p, n, hot):
+    """Every form (comparison-sum for small P, the outer product for
+    large P, searchsorted for pre-sorted ids) matches numpy bincount for
+    in-range pids; ``hot`` puts every id in that one bin."""
+    pids = (rng.integers(0, p, size=n) if hot is None
+            else np.full(n, hot)).astype(np.int32)
+    ref = np.bincount(pids, minlength=p)
+    got = np.asarray(histogram_pids(jnp.asarray(pids), p))
+    np.testing.assert_array_equal(got, ref)
+    got_sorted = np.asarray(histogram_pids(
+        jnp.asarray(pids), p, sorted_ids=jnp.sort(jnp.asarray(pids))))
+    np.testing.assert_array_equal(got_sorted, ref)
 
-    for p in (4, 32, 64, 300):
-        pids = rng.integers(0, p, size=5000).astype(np.int32)
-        ref = np.bincount(pids, minlength=p)
-        got = np.asarray(histogram_pids(jnp.asarray(pids), p))
-        np.testing.assert_array_equal(got, ref)
-        got_sorted = np.asarray(histogram_pids(
-            jnp.asarray(pids), p, sorted_ids=jnp.sort(jnp.asarray(pids))))
-        np.testing.assert_array_equal(got_sorted, ref)
-    # empty partitions + everything-in-one-bucket
-    pids = np.full(100, 3, np.int32)
-    got = np.asarray(histogram_pids(jnp.asarray(pids), 8))
-    assert got[3] == 100 and got.sum() == 100
+
+@pytest.mark.parametrize("p", [4, 33, 64, 256, 300, 1024])
+def test_histogram_pids_drops_out_of_range(rng, p):
+    """Ids outside ``[0, P)`` are dropped, never folded into a bin: the
+    plan's record-count guard depends on it. That includes ids that
+    land on the outer product's grid past ``P`` (P = 300: 300..303)."""
+    grid = 16 * -(-p // 16)
+    stray = np.array([-1, -2**31, p, grid - 1, grid, 2**31 - 1], np.int32)
+    stray = stray[(stray < 0) | (stray >= p)]
+    good = rng.integers(0, p, size=777).astype(np.int32)
+    pids = rng.permutation(np.concatenate([good, np.repeat(stray, 5)]))
+    ref = np.bincount(good, minlength=p)
+    got = np.asarray(histogram_pids(jnp.asarray(pids), p))
+    np.testing.assert_array_equal(got, ref)
+    got_sorted = np.asarray(histogram_pids(
+        jnp.asarray(pids), p, sorted_ids=jnp.sort(jnp.asarray(pids))))
+    np.testing.assert_array_equal(got_sorted, ref)
 
 
 def _dest_major_golden(rng, num_parts, mesh_size, cap, n=200, w=4):

@@ -17,7 +17,8 @@ import jax
 
 from sparkrdma_tpu import MeshRuntime, ShuffleConf
 from sparkrdma_tpu.api.shuffle_manager import ShuffleManager
-from sparkrdma_tpu.exchange.partitioners import (modulo_partitioner,
+from sparkrdma_tpu.exchange.partitioners import (hash_partitioner,
+                                                 modulo_partitioner,
                                                  range_partitioner)
 from sparkrdma_tpu.exchange.protocol import ShuffleExchange, _make_count_fn
 from sparkrdma_tpu.meta.sampling import compute_splitters, make_sampler
@@ -77,6 +78,32 @@ def test_map_side_gather_stays_in_bucket_phase(lowered):
     assert re.search('["/]sr_bucket/', lowered["sort"])
     assert "sr_bucket/sr_sort_gather" not in lowered["sort"]
     assert "sr_bucket/sr_sort_keys" not in lowered["sort"]
+
+
+def _count_hlo(runtime, parts):
+    """Optimised HLO of the plan's count program over a hash
+    partitioner; its ``op_name`` metadata is what a trace's ``tf_op``
+    holds."""
+    x = runtime.shard_records(_rows(4, runtime.num_partitions)[:, :2])
+    fn = _make_count_fn(runtime.mesh, runtime.axis_name, parts,
+                        hash_partitioner(parts))
+    return fn.lower(x).compile().as_text()
+
+
+def test_count_program_histograms_without_sort(runtime):
+    """repartition(256): the size exchange counts in one outer product
+    and sorts nothing; the product's ops stay in ``sr_count``."""
+    hlo = _count_hlo(runtime, 256)
+    assert not re.search(r"\bsort\(", hlo)
+    dots = re.findall(r'op_name="([^"]*dot_general)"', hlo)
+    assert dots and all("sr_count/" in d for d in dots), dots
+
+
+def test_small_count_program_keeps_compare_sum(runtime):
+    hlo = _count_hlo(runtime, 8)
+    assert "dot_general" not in hlo and not re.search(r"\bsort\(", hlo)
+    sums = re.findall(r'op_name="([^"]*reduce_sum)"', hlo)
+    assert sums and all("sr_count/" in s for s in sums), sums
 
 
 def _job(m, sid, rows, splitters=None, end_partition=None):
