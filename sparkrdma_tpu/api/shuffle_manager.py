@@ -316,6 +316,8 @@ class ShuffleReader:
                                 keep_words=self.keep_words,
                                 combine_hint=(self.combine_hint
                                               if fuse_agg else None),
+                                keyed_after=filtered and bool(
+                                    self.key_ordering or self.aggregator),
                             )
                         if filtered:
                             with Timer() as ts, annotate_span(

@@ -200,11 +200,14 @@ class ShuffleConf:
     #: default suits real record counts; tests lower it to exercise the
     #: fast path at CPU-mesh sizes.
     fast_sort_run: int = 1 << 15
-    #: keep arrival order within equal keys on key-ordered reads.
-    #: Spark's sortByKey contract does NOT promise this (so the default
-    #: rides the cheaper unstable network and permits fast_sort); turn
-    #: on for callers that layered meaning onto arrival order. Wide
-    #: records (the key+index path) are stable either way.
+    #: keep arrival order within equal keys on key-ordered reads, and
+    #: within a partition on unordered reads. Spark's sortByKey and
+    #: repartition contracts do NOT promise this (so the default rides
+    #: the cheaper unstable networks — the reduce-side key sort, and
+    #: the map-side bucket sort of unordered, unaggregated reads — and
+    #: permits fast_sort); turn on for callers that layered meaning
+    #: onto arrival order. Wide records (the key+index path) are stable
+    #: either way.
     stable_key_sort: bool = False
 
     #: payload width (in uint32 words) at or above which key-ordering

@@ -745,7 +745,7 @@ class ShuffleExchange:
     @device_phase("sr_bucket")
     def _map_side(self, records, partitioner, num_parts: int,
                   combine: bool, aggregator: str, float_payload: bool,
-                  row_filter, kw_idx):
+                  row_filter, kw_idx, stable: bool):
         """Shared map-side pass, traced inside the local step of BOTH
         regimes: partition, predicate pushdown (filtered rows take the
         out-of-range sentinel pid ``num_parts`` and never occupy a
@@ -754,7 +754,8 @@ class ShuffleExchange:
         the wire), then either the map-side combine pass — whose
         (partition, key) sort already IS the bucketing sort, so its
         compacted counts come from one :func:`bucket_sorted_counts`
-        histogram — or the plain bucketing sort.
+        histogram — or the bucketing sort, stable only where
+        :meth:`exchange` found a read that observes arrival order.
 
         Returns ``(sr, counts, offsets)`` in ``bucket_records``'s
         contract; counts are post-filter/post-combine, so the existing
@@ -786,7 +787,7 @@ class ShuffleExchange:
             recs, pids, np_eff,
             wide=(mode == "wide"),
             ride_words=self.conf.wide_sort_ride_words,
-            pack=(mode == "pack"))
+            pack=(mode == "pack"), stable=stable)
         return sr, counts[:num_parts], offs[:num_parts]
 
     # ------------------------------------------------------------------
@@ -803,7 +804,8 @@ class ShuffleExchange:
                     collective_id: int = 7,
                     combine: bool = False,
                     row_filter: Optional[Callable] = None,
-                    keep_words: Optional[Tuple[int, ...]] = None
+                    keep_words: Optional[Tuple[int, ...]] = None,
+                    stable_buckets: bool = True
                     ) -> Callable:
         """``sort_key_words > 0`` fuses the reduce-side key-ordering sort
         into the same compiled program (one dispatch, one XLA schedule —
@@ -823,7 +825,8 @@ class ShuffleExchange:
         the predicate pushdown; ``keep_words`` the projection pushdown —
         the program moves ``len(keep_words)`` words per record and
         re-widens (zero-fills) on the reduce side, so the output is
-        always full-width ``[W, out_capacity]``."""
+        always full-width ``[W, out_capacity]``. ``stable_buckets``
+        picks the map-side bucket sort (:meth:`exchange` says when)."""
         mesh_size = self.mesh_size
         ppd = num_parts // mesh_size
         ax = self.axis_name
@@ -915,7 +918,7 @@ class ShuffleExchange:
             # --- map-side combine) ------------------------------------
             sr, counts, offs = self._map_side(
                 records, partitioner, num_parts, combine, aggregator,
-                float_payload, row_filter, kw_idx)
+                float_payload, row_filter, kw_idx, stable_buckets)
 
             # --- size exchange (metadata fetch analogue) --------------
             dev_counts = _device_partition_counts(
@@ -1047,7 +1050,8 @@ class ShuffleExchange:
                     aggregator: str = "",
                     float_payload: bool = False,
                     row_filter: Optional[Callable] = None,
-                    keep_words: Optional[Tuple[int, ...]] = None
+                    keep_words: Optional[Tuple[int, ...]] = None,
+                    stable_buckets: bool = True
                     ) -> Callable:
         """records -> (bucketed, counts, offsets, incoming, totals).
 
@@ -1064,7 +1068,7 @@ class ShuffleExchange:
         def local_prep(records):
             sr, counts, offs = self._map_side(
                 records, partitioner, num_parts, combine, aggregator,
-                float_payload, row_filter, kw_idx)
+                float_payload, row_filter, kw_idx, stable_buckets)
             dev_counts = _device_partition_counts(
                 counts, num_parts, mesh_size, ax)
             with jax.named_scope("sr_exchange"):
@@ -1273,7 +1277,7 @@ class ShuffleExchange:
     def _exchange_streaming(self, records, partitioner, plan, num_parts,
                             sort_key_words, aggregator, float_payload,
                             shuffle_id=-1, combine=False, row_filter=None,
-                            keep_words=None):
+                            keep_words=None, stable_buckets=True):
         """Regime B driver: prep, paced round chunks, folds, tail."""
         conf = self.conf
         w = records.shape[0]
@@ -1301,12 +1305,13 @@ class ShuffleExchange:
         from sparkrdma_tpu.exchange.ring import derive_collective_id
 
         prep = cached(("prep", num_parts, w, pkey, fkey, keep_words,
-                       combine, aggregator, float_payload),
+                       combine, aggregator, float_payload, stable_buckets),
                       lambda: self._build_prep(
                           num_parts, w, partitioner, combine=combine,
                           aggregator=aggregator,
                           float_payload=float_payload,
-                          row_filter=row_filter, keep_words=keep_words))
+                          row_filter=row_filter, keep_words=keep_words,
+                          stable_buckets=stable_buckets))
         # tenant folded in: two tenants' identically-shaped streaming
         # exchanges must derive distinct collective ids (and programs)
         chunk_key = ("chunk", self.tenant, num_parts, cap, F, w_eff)
@@ -1432,6 +1437,7 @@ class ShuffleExchange:
         row_filter: Optional[Callable] = None,
         keep_words: Optional[Tuple[int, ...]] = None,
         combine_hint: Optional[Tuple[bool, float]] = None,
+        keyed_after: bool = False,
     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
         """Run the planned exchange.
 
@@ -1452,6 +1458,17 @@ class ShuffleExchange:
             Dropped payload words come back zero-filled in ``out``
             (the :class:`~sparkrdma_tpu.api.serde.RowSchema` of the
             caller tracks which columns are live).
+          keyed_after: the caller sorts or combines ``out`` by key
+            itself (a ranged read does, after its partition filter).
+
+        The map side buckets stably only where a read can observe
+        arrival order within a partition: a key-ordered read (the wide
+        reduce-side sort is stable, so arrival order breaks its ties),
+        an aggregated one (float sums follow it), ``keyed_after``, or
+        ``conf.stable_key_sort``. Every other read (repartition,
+        ``partitionBy``) buckets with the unstable sort, which drops
+        the sort's hidden index operand; counts, offsets and which
+        records land in which partition are the same either way.
 
         Returns ``(out, totals, incoming)``:
           - ``out``: columnar ``uint32[W, mesh*out_capacity]`` — device
@@ -1508,6 +1525,8 @@ class ShuffleExchange:
                     f"keep_words {keep_words} out of range for W={w}")
             if len(keep_words) == w:
                 keep_words = None    # full width: not a projection
+        stable = (self.conf.stable_key_sort or keyed_after
+                  or bool(sort_key_words) or bool(aggregator))
         self._last_wire = None
         self._last_wire_stats = {}
         self._maybe_inject_fault(shuffle_id)
@@ -1550,7 +1569,7 @@ class ShuffleExchange:
                 out, totals, incoming = self._dispatch(
                     records, partitioner, plan, num_parts, shuffle_id,
                     sort_key_words, aggregator, float_payload,
-                    use_combine, row_filter, keep_words)
+                    use_combine, row_filter, keep_words, stable)
             except FetchFailedError:
                 raise
             except Exception as exc:
@@ -1565,22 +1584,55 @@ class ShuffleExchange:
 
     def _dispatch(self, records, partitioner, plan, num_parts, shuffle_id,
                   sort_key_words, aggregator, float_payload,
-                  use_combine, row_filter, keep_words):
+                  use_combine, row_filter, keep_words, stable):
         """One dispatch attempt of the planned exchange (either regime);
         :meth:`exchange` wraps it in the combine-fallback rung."""
         if plan.num_rounds > self.conf.max_rounds_in_flight:
-            return self._exchange_streaming(
+            res = self._exchange_streaming(
                 records, partitioner, plan, num_parts,
                 sort_key_words, aggregator, float_payload,
                 shuffle_id=shuffle_id, combine=use_combine,
-                row_filter=row_filter, keep_words=keep_words)
+                row_filter=row_filter, keep_words=keep_words,
+                stable_buckets=stable)
+        else:
+            res = self._dispatch_fused(
+                records, partitioner, plan, num_parts, shuffle_id,
+                sort_key_words, aggregator, float_payload, use_combine,
+                row_filter, keep_words, stable)
+        self._count_bucket_sort(plan, num_parts, use_combine, row_filter,
+                                keep_words, records.shape[0], stable)
+        return res
+
+    def _count_bucket_sort(self, plan, num_parts, use_combine, row_filter,
+                           keep_words, w, stable) -> None:
+        """Counts the dispatched program's map-side bucket sort:
+        ``exchange.bucket_sort.unstable`` where it dropped stability,
+        ``exchange.bucket_sort.stable`` for every other program that
+        buckets more than one partition (a stable plain or packed sort,
+        the wide branch, the map-side combine's own sort). A single
+        partition is bucketed by no sort: ``bucket_records`` returns it
+        whole, and the one-chip, one-round program skips the map side."""
+        if num_parts == 1 and (row_filter is None or (
+                plan.num_rounds == 1 and self.mesh_size == 1)):
+            return
+        w_eff = len(keep_words) if keep_words is not None else w
+        unstable = (not stable and not use_combine
+                    and self.sort_mode(w_eff) != "wide")
+        self.metrics.counter("exchange.bucket_sort.unstable" if unstable
+                             else "exchange.bucket_sort.stable").inc()
+
+    def _dispatch_fused(self, records, partitioner, plan, num_parts,
+                        shuffle_id, sort_key_words, aggregator,
+                        float_payload, use_combine, row_filter, keep_words,
+                        stable):
+        """The fused regime's dispatch: one program for the exchange."""
         w = records.shape[0]
         donate = self.pool is not None
         with annotate("shuffle:exchange/program"):
             fn, key = self._exec_program(
                 records, partitioner, plan, num_parts, sort_key_words,
                 aggregator, float_payload, use_combine, row_filter,
-                keep_words, donate)
+                keep_words, donate, stable)
         self.last_dispatches = 1
         self.metrics.counter("exchange.dispatches").inc()
         # the phase's timeline pair closes even when the dispatch raises,
@@ -1603,7 +1655,7 @@ class ShuffleExchange:
 
     def _exec_program(self, records, partitioner, plan, num_parts,
                       sort_key_words, aggregator, float_payload,
-                      use_combine, row_filter, keep_words, donate):
+                      use_combine, row_filter, keep_words, donate, stable):
         """The fused regime's program for this exchange, from the cache
         or built: ``(fn, cache key)``."""
         w = records.shape[0]
@@ -1625,7 +1677,7 @@ class ShuffleExchange:
         key = (self.tenant, num_parts, plan.capacity, plan.num_rounds,
                plan.out_capacity,
                w, sort_key_words, aggregator, float_payload, tight,
-               use_combine, fkey, keep_words,
+               use_combine, fkey, keep_words, stable,
                getattr(partitioner, "cache_key", id(partitioner)))
         fn = self._exec_cache.get(key)
         if fn is None:
@@ -1638,7 +1690,8 @@ class ShuffleExchange:
                                   collective_id=derive_collective_id(key),
                                   combine=use_combine,
                                   row_filter=row_filter,
-                                  keep_words=keep_words)
+                                  keep_words=keep_words,
+                                  stable_buckets=stable)
             self._exec_cache[key] = fn
             self._built("exec")
         return fn, key

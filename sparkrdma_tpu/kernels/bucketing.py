@@ -116,17 +116,26 @@ def _outer_histogram(part_ids: jax.Array, num_parts: int) -> jax.Array:
 
 def bucket_records(
     records: jax.Array, part_ids: jax.Array, num_parts: int,
-    wide: bool = False, ride_words: int = 0, pack: bool = False
+    wide: bool = False, ride_words: int = 0, pack: bool = False,
+    stable: bool = True
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Stable-sort a columnar batch ``[W, N]`` by destination partition.
+    """Sort a columnar batch ``[W, N]`` by destination partition.
 
     Returns ``(bucketed [W, N], counts [P], offsets [P])`` where
     ``counts[p]`` is the number of local records bound for partition ``p``
     and ``offsets[p]`` the start of its run — the exact content of Spark's
     shuffle index file. One fused variadic sort: pid is the key, record
-    word columns ride along as values (stable, preserving arrival order
-    within a partition); counts come from the sorted pid vector (see
-    :func:`histogram_pids`), not a scatter.
+    word columns ride along as values; counts come from the sorted pid
+    vector (see :func:`histogram_pids`), not a scatter.
+
+    ``stable`` (the default) keeps arrival order within a partition. XLA
+    makes a sort stable with a hidden s32 iota as its last key, so
+    ``stable=False`` drops an operand and a compare key: on the plain
+    branch at ``W = 2`` the sort carries 3 operands instead of 4. Counts,
+    offsets, partition contiguity and which records land in which
+    partition are the same either way; only the order of records within
+    one partition may differ. The wide branch is stable regardless (its
+    index operand is the permutation it places rows by).
 
     ``pack`` (takes precedence): ride the whole record as u64-PACKED
     operands — pid + ceil(W/2) operands, no gather pass (round-5
@@ -149,7 +158,7 @@ def bucket_records(
         from sparkrdma_tpu.kernels.sort import packed_partition_cols
 
         sorted_ids_u32, bucketed = packed_partition_cols(
-            records, part_ids.astype(jnp.uint32), stable=True)
+            records, part_ids.astype(jnp.uint32), stable=stable)
         sorted_ids = sorted_ids_u32.astype(jnp.int32)
         counts = histogram_pids(part_ids, num_parts, sorted_ids=sorted_ids)
         offsets = jnp.concatenate(
@@ -175,7 +184,7 @@ def bucket_records(
         )
         return bucketed, counts, offsets
     out = lax.sort((part_ids,) + tuple(records[i] for i in range(w)),
-                   num_keys=1, is_stable=True)
+                   num_keys=1, is_stable=stable)
     bucketed = jnp.stack(out[1:])
     counts = histogram_pids(part_ids, num_parts, sorted_ids=out[0])
     offsets = jnp.concatenate(

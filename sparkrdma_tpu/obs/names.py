@@ -55,6 +55,8 @@ COUNTERS = frozenset({
     "exchange.exchanges",
     "exchange.rounds",
     "exchange.records",
+    "exchange.bucket_sort.stable",
+    "exchange.bucket_sort.unstable",
     "combine.gate_on",
     "combine.gate_off",
     "combine.fallbacks",

@@ -34,6 +34,43 @@ def test_bucket_records_matches_numpy(rng):
         off += len(ref)
 
 
+def _canon(rows):
+    return rows[np.lexsort(tuple(rows[:, c] for c in range(rows.shape[1])))]
+
+
+@pytest.mark.parametrize("pack", [False, True], ids=["plain", "pack"])
+def test_bucket_records_unstable_keeps_index_and_multisets(rng, pack):
+    """``stable=False`` may reorder records within a partition and
+    nothing else: counts and offsets exact, every partition's run holds
+    its records as a multiset, and a row filter's sentinel pid
+    ``num_parts`` sorts to the tail, outside every count."""
+    n, p = 300, 8
+    rows = rng.integers(0, 2**32, size=(n, 4), dtype=np.uint32)
+    pids_np = rng.integers(0, p, size=n).astype(np.int32)
+    dropped = rng.random(n) < 0.2
+    pids_np[dropped] = p
+    sr, counts, offs = bucket_records(_cols(rows), jnp.asarray(pids_np), p,
+                                      pack=pack, stable=False)
+    np_counts = np.bincount(pids_np[~dropped], minlength=p)
+    np.testing.assert_array_equal(np.asarray(counts), np_counts)
+    np.testing.assert_array_equal(
+        np.asarray(offs), np.concatenate([[0], np.cumsum(np_counts)[:-1]]))
+    assert int(np.asarray(counts).sum()) == n - dropped.sum()
+    sr_rows = np.asarray(sr).T
+    for part in range(p):
+        off = int(offs[part])
+        np.testing.assert_array_equal(
+            _canon(sr_rows[off:off + np_counts[part]]),
+            _canon(rows[pids_np == part]))
+    np.testing.assert_array_equal(_canon(sr_rows[n - dropped.sum():]),
+                                  _canon(rows[dropped]))
+    # the index half is the stable form's, bit for bit
+    _, s_counts, s_offs = bucket_records(
+        _cols(rows), jnp.asarray(pids_np), p, pack=pack)
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(s_counts))
+    np.testing.assert_array_equal(np.asarray(offs), np.asarray(s_offs))
+
+
 def test_fill_round_slots_covers_all_records_across_rounds(rng):
     n, p, cap = 100, 4, 8
     rows = rng.integers(1, 2**32, size=(n, 4), dtype=np.uint32)

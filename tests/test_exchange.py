@@ -3,8 +3,13 @@
 Golden test per SURVEY.md §4: the shuffled output must be, per destination
 partition, exactly the input records whose partitioner says they belong
 there (a permutation grouped by source order) — verified against a pure
-numpy reference shuffle.
+numpy reference shuffle. Arrival order within a partition is part of that
+only under ``conf.stable_key_sort``: by default an unordered read buckets
+with the unstable sort, and each partition holds the reference's records
+as a multiset at the reference's offset.
 """
+
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -43,19 +48,53 @@ def collect_valid_rows(out, totals, cap):
          for d in range(len(totals))])
 
 
-def np_reference_shuffle(x, pids, num_parts, mesh_size, n_per_dev):
-    """Expected per-device received sets, honoring (partition, source) order."""
+def np_reference_parts(x, pids, num_parts, mesh_size, n_per_dev):
+    """Expected per-device received partitions, in local partition order,
+    each honoring source order."""
     out = {}
     for d in range(mesh_size):
-        rows = []
+        parts = []
         for q in range(num_parts // mesh_size):
             p = q * mesh_size + d
+            rows = []
             for s in range(mesh_size):
                 src_rows = x[s * n_per_dev:(s + 1) * n_per_dev]
                 src_pids = pids[s * n_per_dev:(s + 1) * n_per_dev]
                 rows.append(src_rows[src_pids == p])
-        out[d] = np.concatenate(rows) if rows else np.zeros((0, x.shape[1]))
+            parts.append(np.concatenate(rows))
+        out[d] = parts
     return out
+
+
+def canon_rows(a):
+    """Rows in lexicographic order: a multiset's canonical form."""
+    return a[np.lexsort(tuple(a[:, c] for c in range(a.shape[1])))]
+
+
+def assert_device_rows(got, parts, arrival_order):
+    """One device's valid rows against its reference partitions. With
+    ``arrival_order`` (``stable_key_sort``) they are equal row for row;
+    without it, each partition's run, at the reference's offset, holds
+    the reference's rows as a multiset."""
+    ref = np.concatenate(parts)
+    assert len(got) == len(ref)
+    if arrival_order:
+        np.testing.assert_array_equal(got, ref)
+        return
+    off = 0
+    for part in parts:
+        np.testing.assert_array_equal(canon_rows(got[off:off + len(part)]),
+                                      canon_rows(part))
+        off += len(part)
+
+
+def order_variant(exchange_rt, stable_key_sort):
+    """``exchange_rt`` with ``conf.stable_key_sort`` as given."""
+    ex, rt = exchange_rt
+    if ex.conf.stable_key_sort == stable_key_sort:
+        return exchange_rt
+    conf = dataclasses.replace(ex.conf, stable_key_sort=stable_key_sort)
+    return ShuffleExchange(rt.mesh, rt.axis_name, conf), rt
 
 
 def run_and_check(exchange_rt, x_global, x_np, part_fn, num_parts, rng):
@@ -63,55 +102,65 @@ def run_and_check(exchange_rt, x_global, x_np, part_fn, num_parts, rng):
     pids = np.asarray(part_fn(jnp.asarray(x_np.T)))
     out, totals, plan = ex.shuffle(x_global, part_fn, num_parts=num_parts)
     n_per_dev = x_np.shape[0] // rt.num_partitions
-    ref = np_reference_shuffle(x_np, pids, num_parts, rt.num_partitions,
-                               n_per_dev)
+    ref = np_reference_parts(x_np, pids, num_parts, rt.num_partitions,
+                             n_per_dev)
     cap = plan.out_capacity
     out_np = np.asarray(out)                      # columnar [W, mesh*cap]
     totals_np = np.asarray(totals)
     for d in range(rt.num_partitions):
         k = int(totals_np[d])
-        assert k == len(ref[d]), f"device {d}: {k} != {len(ref[d])}"
+        n_ref = sum(len(part) for part in ref[d])
+        assert k == n_ref, f"device {d}: {k} != {n_ref}"
         dev = out_np[:, d * cap:(d + 1) * cap]
-        np.testing.assert_array_equal(dev[:, :k].T, ref[d])
+        assert_device_rows(dev[:, :k].T, ref[d], ex.conf.stable_key_sort)
         assert not np.any(dev[:, k:])
     # conservation: every record arrives exactly once
     assert totals_np.sum() == x_np.shape[0]
     return plan
 
 
-def test_single_round_exchange(exchange, rng):
+@pytest.mark.parametrize("stable_key_sort", [True, False])
+def test_single_round_exchange(exchange, rng, stable_key_sort):
     _, rt = exchange
     xg, xn = make_global_records(rng, rt, 32)
-    plan = run_and_check(exchange, xg, xn, modulo_partitioner(8), 8, rng)
+    plan = run_and_check(order_variant(exchange, stable_key_sort), xg, xn,
+                         modulo_partitioner(8), 8, rng)
     assert plan.num_rounds == 1
 
 
-def test_multi_round_streaming(exchange, rng):
+@pytest.mark.parametrize("stable_key_sort", [True, False])
+def test_multi_round_streaming(exchange, rng, stable_key_sort):
     """Skewed partitions larger than one slot stream across rounds."""
     _, rt = exchange
     n_per_dev = 64  # worst case 64 records from one src to one dest > 16
     x = rng.integers(1, 2**32, size=(n_per_dev * 8, 4), dtype=np.uint32)
     x[:, 0] = 0  # every record on device 0..7 hashes to partition 0 % 8
     xg = rt.shard_records(x)
-    plan = run_and_check(exchange, xg, x, modulo_partitioner(8), 8, rng)
+    plan = run_and_check(order_variant(exchange, stable_key_sort), xg, x,
+                         modulo_partitioner(8), 8, rng)
     assert plan.num_rounds == int(np.ceil(64 / 16))
 
 
-def test_hash_partitioner_balance_and_correctness(exchange, rng):
+@pytest.mark.parametrize("stable_key_sort", [True, False])
+def test_hash_partitioner_balance_and_correctness(exchange, rng,
+                                                 stable_key_sort):
     _, rt = exchange
     xg, xn = make_global_records(rng, rt, 64)
     part = hash_partitioner(8)
-    run_and_check(exchange, xg, xn, part, 8, rng)
+    run_and_check(order_variant(exchange, stable_key_sort), xg, xn, part, 8,
+                  rng)
     pids = np.asarray(part(jnp.asarray(xn.T)))
     counts = np.bincount(pids, minlength=8)
     assert counts.min() > 0.5 * counts.mean()  # rough balance on random keys
 
 
-def test_parts_per_device_gt_one(exchange, rng):
+@pytest.mark.parametrize("stable_key_sort", [True, False])
+def test_parts_per_device_gt_one(exchange, rng, stable_key_sort):
     """num_parts = 2x mesh: two reduce partitions per chip."""
     _, rt = exchange
     xg, xn = make_global_records(rng, rt, 32)
-    run_and_check(exchange, xg, xn, modulo_partitioner(16), 16, rng)
+    run_and_check(order_variant(exchange, stable_key_sort), xg, xn,
+                  modulo_partitioner(16), 16, rng)
 
 
 def test_range_partitioner_lexicographic(rng):
@@ -127,14 +176,16 @@ def test_range_partitioner_lexicographic(rng):
     np.testing.assert_array_equal(np.asarray(part(recs)), [0, 1, 1, 1, 2, 2])
 
 
-def test_empty_partitions_ok(exchange, rng):
+@pytest.mark.parametrize("stable_key_sort", [True, False])
+def test_empty_partitions_ok(exchange, rng, stable_key_sort):
     """A partitioner that sends everything to one partition leaves the rest
     empty — totals must still be exact (zero), no crash."""
     _, rt = exchange
     x = rng.integers(1, 2**32, size=(8 * 8, 4), dtype=np.uint32)
     x[:, 0] = 5
     xg = rt.shard_records(x)
-    run_and_check(exchange, xg, x, modulo_partitioner(8), 8, rng)
+    run_and_check(order_variant(exchange, stable_key_sort), xg, x,
+                  modulo_partitioner(8), 8, rng)
 
 
 def test_plan_splits_excessive_skew(exchange, rng):
@@ -209,7 +260,8 @@ def test_split_plan_serves_partition_range_reads(rng):
         m.unregister_shuffle(60)
 
 
-def test_repartition_256_geometry(exchange, rng):
+@pytest.mark.parametrize("stable_key_sort", [True, False])
+def test_repartition_256_geometry(exchange, rng, stable_key_sort):
     """BASELINE config 1's geometry: 256 partitions on the 8-chip mesh
     (32 partitions per device), both regimes.
 
@@ -224,27 +276,29 @@ def test_repartition_256_geometry(exchange, rng):
     _, rt = exchange
     xg, xn = make_global_records(rng, rt, 512)
     part = hash_partitioner(256)
-    plan = run_and_check(exchange, xg, xn, part, 256, rng)
+    plan = run_and_check(order_variant(exchange, stable_key_sort), xg, xn,
+                         part, 256, rng)
     assert plan.num_rounds == 1  # balanced: auto-sized capacity, one round
 
     # streaming regime at the same partition count: small explicit slots
     # force multiple rounds through the chunk/fold path (fori_loop fold
     # at ppd=32); full golden content check, not just conservation
-    conf = ShuffleConf(slot_records=2, max_rounds=16, max_rounds_in_flight=1)
+    conf = ShuffleConf(slot_records=2, max_rounds=16, max_rounds_in_flight=1,
+                       stable_key_sort=stable_key_sort)
     ex2 = ShuffleExchange(rt.mesh, rt.axis_name, conf)
     plan2 = ex2.plan(xg, part, num_parts=256, capacity=2)
     assert plan2.num_rounds > 1
     out2, tot2, _ = ex2.exchange(xg, part, plan2)
     pids = np.asarray(part(jnp.asarray(xn.T)))
     n_per_dev = xn.shape[0] // rt.num_partitions
-    ref = np_reference_shuffle(xn, pids, 256, rt.num_partitions, n_per_dev)
+    ref = np_reference_parts(xn, pids, 256, rt.num_partitions, n_per_dev)
     out_np, tot_np = np.asarray(out2), np.asarray(tot2)
     cap = plan2.out_capacity
     for d in range(rt.num_partitions):
         k = int(tot_np[d])
-        assert k == len(ref[d])
-        np.testing.assert_array_equal(
-            out_np[:, d * cap:d * cap + k].T, ref[d])
+        assert k == sum(len(part) for part in ref[d])
+        assert_device_rows(out_np[:, d * cap:d * cap + k].T, ref[d],
+                           stable_key_sort)
     assert tot_np.sum() == xn.shape[0]
 
 
@@ -296,11 +350,14 @@ class TestPallasRingTransport:
         np.testing.assert_array_equal(np.asarray(tot_x), np.asarray(tot_r))
         np.testing.assert_array_equal(np.asarray(out_x), np.asarray(out_r))
 
-    def test_ring_correct_vs_numpy(self, ring_exchange, rng):
+    @pytest.mark.parametrize("stable_key_sort", [True, False])
+    def test_ring_correct_vs_numpy(self, ring_exchange, rng,
+                                   stable_key_sort):
         """The ring transport independently passes the golden check."""
         _, rt = ring_exchange
         xg, xn = make_global_records(rng, rt, 24)
-        run_and_check(ring_exchange, xg, xn, modulo_partitioner(8), 8, rng)
+        run_and_check(order_variant(ring_exchange, stable_key_sort), xg, xn,
+                      modulo_partitioner(8), 8, rng)
 
 
 def test_plan_split_extreme_odd_factor(exchange, rng):
@@ -344,24 +401,30 @@ class TestHierarchicalTransport:
         np.testing.assert_array_equal(np.asarray(tot_f), np.asarray(tot_h))
         np.testing.assert_array_equal(np.asarray(out_f), np.asarray(out_h))
 
-    def test_correct_vs_numpy_multi_round(self, exchange, rng):
+    @pytest.mark.parametrize("stable_key_sort", [True, False])
+    def test_correct_vs_numpy_multi_round(self, exchange, rng,
+                                          stable_key_sort):
         """Hierarchical transport independently passes the golden check,
         including streaming rounds."""
         _, rt = exchange
         conf = ShuffleConf(slot_records=16, transport="hierarchical",
-                           hierarchy_hosts=2)
+                           hierarchy_hosts=2,
+                           stable_key_sort=stable_key_sort)
         ex_h = ShuffleExchange(rt.mesh, rt.axis_name, conf)
         xg, xn = make_global_records(rng, rt, 80)
         run_and_check((ex_h, rt), xg, xn, modulo_partitioner(8), 8, rng)
 
-    def test_auto_hosts_single_process_degenerates(self, exchange, rng):
+    @pytest.mark.parametrize("stable_key_sort", [True, False])
+    def test_auto_hosts_single_process_degenerates(self, exchange, rng,
+                                                   stable_key_sort):
         """hosts auto-resolves to 1 in a single process: flat path, still
         correct (the degenerate-hierarchy branch)."""
         from sparkrdma_tpu.exchange.hierarchical import hierarchy_for
 
         _, rt = exchange
         assert hierarchy_for(rt.mesh, rt.axis_name, 0) == 1
-        conf = ShuffleConf(slot_records=16, transport="hierarchical")
+        conf = ShuffleConf(slot_records=16, transport="hierarchical",
+                           stable_key_sort=stable_key_sort)
         ex_h = ShuffleExchange(rt.mesh, rt.axis_name, conf)
         xg, xn = make_global_records(rng, rt, 24)
         run_and_check((ex_h, rt), xg, xn, modulo_partitioner(8), 8, rng)
@@ -624,7 +687,9 @@ class TestPushdownExchange:
     never occupy a slot, dropped words never hit the wire (re-widened
     zero-filled on the reader)."""
 
-    def test_row_filter_matches_prefiltered_shuffle(self, exchange, rng):
+    @pytest.mark.parametrize("stable_key_sort", [True, False])
+    def test_row_filter_matches_prefiltered_shuffle(self, exchange, rng,
+                                                    stable_key_sort):
         _, rt = exchange
         xg, xn = make_global_records(rng, rt, 32)
         part = modulo_partitioner(8)
@@ -634,14 +699,17 @@ class TestPushdownExchange:
 
         keep_even.cache_key = ("keep_even_w2",)
         ex = ShuffleExchange(rt.mesh, rt.axis_name,
-                             ShuffleConf(slot_records=16))
+                             ShuffleConf(slot_records=16,
+                                         stable_key_sort=stable_key_sort))
         plan = ex.plan(xg, part, num_parts=8)
         out, tot, _ = ex.exchange(xg, part, plan, row_filter=keep_even)
         mask = (xn[:, 2] & 1) == 0
         kept = xn[mask]
         pids = np.asarray(part(jnp.asarray(kept.T)))
         # reference: shuffle of the PRE-filtered rows. Source order is
-        # preserved within each device, so the reference applies.
+        # preserved within each device under stable_key_sort, so the
+        # reference applies row for row; else as a multiset (device d
+        # holds the one partition d).
         n_per_dev = xn.shape[0] // rt.num_partitions
         dev_of = np.repeat(np.arange(rt.num_partitions), n_per_dev)[mask]
         cap = plan.out_capacity
@@ -652,8 +720,8 @@ class TestPushdownExchange:
                  for s in range(rt.num_partitions)])
             k = int(tot_np[d])
             assert k == len(ref)
-            np.testing.assert_array_equal(
-                out_np[:, d * cap:d * cap + k].T, ref)
+            assert_device_rows(out_np[:, d * cap:d * cap + k].T, [ref],
+                               stable_key_sort)
         assert tot_np.sum() == mask.sum()
         ws = ex.wire_stats()
         assert ws["pushdown_rows_dropped"] == int((~mask).sum())
@@ -881,13 +949,15 @@ class TestRingFusedExchange:
         np.testing.assert_array_equal(np.asarray(tot_f), np.asarray(tot_u))
         np.testing.assert_array_equal(np.asarray(out_f), np.asarray(out_u))
 
-    def test_fused_golden_vs_numpy(self, fused_exchange, rng):
+    @pytest.mark.parametrize("stable_key_sort", [True, False])
+    def test_fused_golden_vs_numpy(self, fused_exchange, rng,
+                                   stable_key_sort):
         """The fused transport independently passes the golden check
         (repartition shape)."""
         _, rt = fused_exchange
         xg, xn = make_global_records(rng, rt, 24)
-        run_and_check(fused_exchange, xg, xn, hash_partitioner(16), 16,
-                      rng)
+        run_and_check(order_variant(fused_exchange, stable_key_sort), xg,
+                      xn, hash_partitioner(16), 16, rng)
 
     def test_parity_streaming_regime(self, rng):
         """Guaranteed streaming regime (rounds > max_rounds_in_flight):

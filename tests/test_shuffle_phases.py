@@ -175,6 +175,76 @@ def test_streaming_regime_counts_its_programs(tmp_path):
         assert _built(m, "exec") == 0
 
 
+#: what each read asks of the exchange, and whether it can observe
+#: arrival order within a partition
+READS = {
+    "unordered": ({}, False),
+    "key_ordered": ({"sort_key_words": KW}, True),
+    "aggregated": ({"aggregator": "sum"}, True),
+    "keyed_after": ({"keyed_after": True}, True),
+}
+
+
+@pytest.mark.parametrize("stable_key_sort", [False, True])
+@pytest.mark.parametrize("read", sorted(READS))
+def test_bucket_sort_is_stable_where_arrival_order_shows(runtime, read,
+                                                         stable_key_sort):
+    """Only an unordered, unaggregated read without ``stable_key_sort``
+    buckets with the unstable sort; every other read builds the stable
+    one, and each dispatch counts which it ran."""
+    from sparkrdma_tpu.obs.metrics import MetricsRegistry
+
+    kwargs, observable = READS[read]
+    stable = observable or stable_key_sort
+    reg = MetricsRegistry(enabled=True)
+    ex = ShuffleExchange(runtime.mesh, runtime.axis_name,
+                         ShuffleConf(stable_key_sort=stable_key_sort),
+                         metrics=reg)
+    parts = runtime.num_partitions
+    x = runtime.shard_records(_rows(12, parts))
+    part = modulo_partitioner(parts)
+    plan = ex.plan(x, part)
+    for _ in range(2):
+        ex.exchange(x, part, plan, **kwargs)
+    (fn,) = ex._exec_cache.values()
+    sorts = [ln for ln in fn.lower(x).compile().as_text().splitlines()
+             if re.search(r"\bsort\(", ln) and "sr_bucket/" in ln]
+    assert sorts and all(("is_stable=true" in ln) == stable
+                         for ln in sorts), sorts
+    counts = {k: reg.counter(f"exchange.bucket_sort.{k}").value
+              for k in ("stable", "unstable")}
+    assert counts == ({"stable": 2, "unstable": 0} if stable
+                      else {"stable": 0, "unstable": 2})
+
+
+@pytest.mark.parametrize("job,kind", [
+    ("repartition", "unstable"),
+    ("streaming", "unstable"),
+    ("ranged_key_ordered", "stable"),
+])
+def test_manager_jobs_count_their_bucket_sort(tmp_path, job, kind):
+    """Whole jobs through the manager: a repartition counts the unstable
+    sort once a job, in either regime; a ranged key-ordered read sorts
+    after the exchange, so its map side stays stable."""
+    streaming = job == "streaming"
+    conf = ShuffleConf(slot_records=8 if streaming else 64,
+                       max_rounds_in_flight=1 if streaming else 8,
+                       metrics_sink=str(tmp_path / "j.jsonl"))
+    with ShuffleManager(MeshRuntime(conf), conf) as m:
+        parts = m.runtime.num_partitions
+        rows = _rows(13, parts)
+        if streaming:
+            rows[:, 0] = 0                   # one hot partition: rounds > 1
+        end = parts - 1 if job == "ranged_key_ordered" else None
+        for sid in (1, 2):
+            _job(m, sid, rows, end_partition=end)
+        if streaming:
+            assert _built(m, "prep") == 1
+        counts = {k: m.metrics.counter(f"exchange.bucket_sort.{k}").value
+                  for k in ("stable", "unstable")}
+    assert counts[kind] == 2 and sum(counts.values()) == 2, counts
+
+
 #: host spans of one range-partitioned job, and the span each one must
 #: lie inside
 NESTED = {
