@@ -89,13 +89,17 @@ def pallas_kernels(manager) -> str:
     ran = [f"{name} x{c(counter).value}" for name, counter in (
         ("fused ring", "transport.ring.fused_kernels"),
         ("per-round ring", "transport.ring.kernels")) if c(counter).value]
-    if conf.fast_sort:
-        ran.append("merge_sort_cols (when the plan supports it)")
     if not ran:
-        return (f"none (transport={conf.transport}, "
-                f"fast_sort={conf.fast_sort})")
-    mode = "interpreted" if manager._exchange.interpret else "compiled"
+        return f"none (transport={conf.transport})"
+    mode = "interpreted" if interpreted(manager) else "compiled"
     return f"{', '.join(ran)}: {mode}"
+
+
+def interpreted(manager) -> bool:
+    """Does this manager run its Pallas kernels interpreted?"""
+    from sparkrdma_tpu.runtime.mesh import mesh_interpret
+
+    return mesh_interpret(manager.runtime.mesh)
 
 
 def terasort_phase(devices, seed, clock, transport="xla", host_check=True):
@@ -122,7 +126,6 @@ def terasort_phase(devices, seed, clock, transport="xla", host_check=True):
         collect_shuffle_read_stats=True)
     manager = ShuffleManager(MeshRuntime(conf, devices=devices), conf)
     try:
-        ex = manager._exchange
         c0, t0 = clock.seconds, time.perf_counter()
         res, out, totals = run_terasort(
             manager, records_per_device=RECORDS, seed=seed,
@@ -133,7 +136,8 @@ def terasort_phase(devices, seed, clock, transport="xla", host_check=True):
             "transport": transport,
             "records_per_chip": RECORDS,
             "record_bytes": res.record_bytes,
-            "sort_mode": ex.sort_mode(HIBENCH_VAL_WORDS + conf.key_words),
+            "sort_mode": conf.sort_strategy(
+                HIBENCH_VAL_WORDS + conf.key_words).kind,
             "verified": bool(res.verified),
             "host_order_check": host_check,
             "phase_s": time.perf_counter() - t0,
@@ -142,7 +146,7 @@ def terasort_phase(devices, seed, clock, transport="xla", host_check=True):
             "repeats": REPEATS,
             "peak_bytes_in_use": peak_bytes(devices),
             "output_devices": len(shards),
-            "pallas_interpret": ex.interpret,
+            "pallas_interpret": interpreted(manager),
             "pallas_kernels": pallas_kernels(manager),
             "ring_kernels_traced": manager.metrics.counter(
                 "transport.ring.fused_kernels").value,
@@ -207,7 +211,7 @@ def repartition_phase(devices, seed, clock):
                  "verified": ok, "phase_s": time.perf_counter() - t0,
                  "compile_s": clock.seconds - c0,
                  "peak_bytes_in_use": peak_bytes(devices),
-                 "pallas_interpret": m._exchange.interpret,
+                 "pallas_interpret": interpreted(m),
                  "pallas_kernels": pallas_kernels(m),
                  **fallbacks(m)}
         m.unregister_shuffle(0)

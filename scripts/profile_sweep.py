@@ -27,7 +27,6 @@ Suites (``profile_sweep.py SUITE``; origin script in parens):
   fastsort   (profile4)  chunked sort + bitonic merge hierarchy probes
   pipeline   (profile5)  dispatch pipelining, gather/scatter, merge_pass
   bench      (profile6)  decompose the real bench-geometry read program
-  mergepath  (profile7)  compiled merge-path sort: correctness + speed
   wide       (profile8)  wide-record (100B) strategies; --case sorts|
                          take_rows:<chunks>[:w]|take_cols[:w]|
                          chunk_sort:<T>|floor
@@ -502,41 +501,6 @@ def suite_bench(a, rng):
 
 
 # ----------------------------------------------------------------------
-# mergepath (profile7): compiled merge-path sort, correctness + speed
-# ----------------------------------------------------------------------
-def suite_mergepath(a, rng):
-    from sparkrdma_tpu.kernels.merge_sort import merge_sort_cols
-
-    n, w = a.records, a.words
-    cols = random_cols(rng, w, n)
-
-    def mono(c):
-        out = lax.sort(tuple(c[i] for i in range(w)), num_keys=w,
-                       is_stable=False)
-        return jnp.stack(out)
-
-    # correctness first (shared input, device equality)
-    ref = jax.jit(mono)(cols)
-    for run, tile in ((1 << 15, 1 << 15), (1 << 16, 1 << 15)):
-        got = jax.jit(
-            lambda c: merge_sort_cols(c, run=run, tile=tile))(cols)
-        eq = bool(jnp.array_equal(ref, got))
-        print(f"run={run} tile={tile} correct={eq}", flush=True)
-        if not eq:
-            return 1
-
-    slope_probe("monolithic lax.sort (full-record key)", mono, cols,
-                bytes_moved=n * w * 4)
-    for run, tile in ((1 << 15, 1 << 15), (1 << 16, 1 << 15),
-                      (1 << 16, 1 << 16)):
-        slope_probe(f"merge_sort run={run} tile={tile}",
-                    lambda c, r=run, t=tile: merge_sort_cols(
-                        c, run=r, tile=t),
-                    cols, bytes_moved=n * w * 4)
-    return 0
-
-
-# ----------------------------------------------------------------------
 # wide (profile8): wide-record (100B) strategies
 # ----------------------------------------------------------------------
 def suite_wide(a, rng):
@@ -857,6 +821,7 @@ def suite_ab(a, rng):
                  lambda c: lexsort_cols(c, 2, stable=False), cols, n * 100)
     elif case == "bucket25":
         from sparkrdma_tpu.kernels.bucketing import bucket_records
+        from sparkrdma_tpu.kernels.sort import SortStrategy
         cols = np.zeros((26, n), dtype=np.uint32)
         cols[0] = rng.integers(0, 8, size=n)       # pid
         cols[1:] = rng.integers(0, 2**32, size=(25, n), dtype=np.uint32)
@@ -868,8 +833,8 @@ def suite_ab(a, rng):
         time_one("bucket wide (pid+10 ride+idx, gather)",
                  lambda c: jnp.concatenate([
                      c[:1] * 0,  # placeholder row to keep shapes equal
-                     bucket_records(c[1:], c[0], 8, wide=True,
-                                    ride_words=10)[0]]),
+                     bucket_records(c[1:], c[0], 8,
+                                    SortStrategy("wide", 10))[0]]),
                  cols, n * 104)
     else:
         raise SystemExit(f"unknown ab case {case}")
@@ -936,7 +901,6 @@ SUITES = {
     "fastsort": suite_fastsort,
     "pipeline": suite_pipeline,
     "bench": suite_bench,
-    "mergepath": suite_mergepath,
     "wide": suite_wide,
     "width": suite_width,
     "mapside": suite_mapside,

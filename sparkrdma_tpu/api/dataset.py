@@ -39,6 +39,7 @@ from jax import lax
 from sparkrdma_tpu.api.shuffle_manager import ShuffleManager
 from sparkrdma_tpu.exchange.partitioners import (hash_partitioner,
                                                  range_partitioner)
+from sparkrdma_tpu.kernels.sort import sort_by_lead_cols
 from sparkrdma_tpu.meta.sampling import compute_splitters, make_sampler
 from sparkrdma_tpu.obs import trace as _trace
 
@@ -102,13 +103,11 @@ def _join_program(manager: ShuffleManager, ca: int, cb: int,
     kw = manager.conf.key_words
     null = jnp.uint32(_NULL)
 
-    mode = manager._exchange.sort_mode(manager.conf.record_words)
+    strategy = manager.conf.sort_strategy(manager.conf.record_words)
 
     def compact_valid(r, v):
-        # re-compact validity as a prefix (strategy per sort_mode)
-        from sparkrdma_tpu.kernels.sort import sort_by_lead_cols
-
-        return sort_by_lead_cols(r, ~v, mode)
+        # re-compact validity as a prefix
+        return sort_by_lead_cols(r, (~v).astype(jnp.uint32), strategy)[1]
 
     def local(ra, ta, rb, tb):
         # mask reserved null-key filler so it can never join with the
@@ -158,15 +157,12 @@ def _join_rows_program(manager: ShuffleManager, ca: int, cb: int,
     kw = manager.conf.key_words
     vw = manager.conf.val_words
     null = jnp.uint32(_NULL)
-    mode = manager._exchange.sort_mode(manager.conf.record_words)
-    pack = mode == "pack"
+    strategy = manager.conf.sort_strategy(manager.conf.record_words)
 
     def strip_filler(r, t, cap):
-        from sparkrdma_tpu.kernels.sort import sort_by_lead_cols
-
         v = _valid_nonfiller(r, t, cap, kw)
         r = jnp.where(v[None], r, jnp.uint32(0))
-        r = sort_by_lead_cols(r, ~v, mode)
+        _, r = sort_by_lead_cols(r, (~v).astype(jnp.uint32), strategy)
         return r, jnp.sum(v).astype(jnp.int32)[None]
 
     def local(ra, ta, rb, tb):
@@ -179,7 +175,7 @@ def _join_rows_program(manager: ShuffleManager, ca: int, cb: int,
                                key_ix=key_ix, pay_ix=kw)
             return c[None]
         joined, count = _local_join_rows(ra, ta, rb, tb, out_capacity,
-                                         key_ix, kw, vw, vw, pack=pack)
+                                         key_ix, kw, vw, vw, strategy)
         return joined, count[None]
 
     from sparkrdma_tpu.workloads.join import _local_join
@@ -675,13 +671,12 @@ class Dataset:
             rt = m.runtime
             ax = rt.axis_name
             null = jnp.uint32(_NULL)
-            mode = m._exchange.sort_mode(w)
+            strategy = m.conf.sort_strategy(w)
 
             def local(r, t):
-                from sparkrdma_tpu.kernels.sort import sort_by_lead_cols
-
                 valid = _valid_nonfiller(r, t, cap, kw)
-                packed = sort_by_lead_cols(r, ~valid, mode)
+                _, packed = sort_by_lead_cols(
+                    r, (~valid).astype(jnp.uint32), strategy)
                 packed = packed[:, :new_cap]
                 live = jnp.arange(new_cap) < jnp.sum(valid)
                 return jnp.where(live[None], packed, null)
@@ -881,7 +876,7 @@ class Dataset:
         w = m.conf.record_words
         kw = m.conf.key_words
         num_parts = m.runtime.num_partitions
-        pack = m._exchange.sort_mode(w) == "pack"
+        strategy = m.conf.sort_strategy(w)
 
         def full_row_hash(records):
             h = jnp.uint32(0x9E3779B9)
@@ -909,9 +904,9 @@ class Dataset:
 
             def local(r, t):
                 valid = _valid_nonfiller(r, t, cap, kw)
-                # dedupe = combine keyed on EVERY word (payload empty);
-                # packed for wide records (keys pack pairwise too)
-                out, nuniq = combine_by_key_cols(r, valid, w, pack=pack)
+                # dedupe = combine keyed on EVERY word (payload empty)
+                out, nuniq = combine_by_key_cols(r, valid, w,
+                                                 strategy=strategy)
                 return out, nuniq[None]
 
             fn = jax.jit(shard_map(
@@ -976,14 +971,12 @@ class Dataset:
         rt = m.runtime
         ax = rt.axis_name
         null = jnp.uint32(_NULL)
-        mode = m._exchange.sort_mode(w)
-        pack, wide = mode == "pack", mode == "wide"
-        ride = m.conf.wide_sort_ride_words
+        strategy = m.conf.sort_strategy(w)
 
         def local(r, t):
             valid = _valid_nonfiller(r, t, cap, kw)
             values, groups, n_groups, total = group_runs_cols(
-                r, valid, kw, wide=wide, ride_words=ride, pack=pack)
+                r, valid, kw, strategy)
             return values, groups, n_groups[None], total[None]
 
         fn = jax.jit(shard_map(

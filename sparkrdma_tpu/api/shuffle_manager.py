@@ -48,7 +48,7 @@ from sparkrdma_tpu.exchange.errors import (FetchFailedError,
                                            UnrecoverableShuffleError)
 from sparkrdma_tpu.exchange.protocol import ShuffleExchange, ShufflePlan
 from sparkrdma_tpu.hbm.tiered_store import TieredStore, store_totals
-from sparkrdma_tpu.kernels.sort import lexsort_cols
+from sparkrdma_tpu.kernels.sort import sort_by_key_cols, sort_by_lead_cols
 from sparkrdma_tpu.meta.checkpoint import MapOutputStore
 from sparkrdma_tpu.meta.map_output import MapOutputRegistry
 from sparkrdma_tpu.obs import critical_path
@@ -1228,14 +1228,12 @@ class ShuffleManager:
         ranks = self.runtime.shard_rows(seg_rank)
 
         w = out.shape[0]
-        mode = self._exchange.sort_mode(w)
-        key = ("splitfilter", cap, w, s_total, mode)
+        strategy = self.conf.sort_strategy(w)
+        key = ("splitfilter", cap, w, s_total, strategy)
         fn = self._filter_cache.get(key)
         if fn is None:
             from jax import shard_map
             from jax.sharding import PartitionSpec as P
-
-            from sparkrdma_tpu.kernels.sort import sort_by_lead_cols
 
             ax = self.runtime.axis_name
             sentinel = jnp.uint32(0xFFFFFFFF)
@@ -1250,7 +1248,7 @@ class ShuffleManager:
                                  sentinel)
                 ln = jnp.sum(rank != sentinel).astype(jnp.int32)
                 live = (r < ln)
-                packed = sort_by_lead_cols(cols, rank, mode)
+                _, packed = sort_by_lead_cols(cols, rank, strategy)
                 packed = packed * live[None].astype(packed.dtype)
                 return packed, ln[None]
 
@@ -1282,15 +1280,12 @@ class ShuffleManager:
             from jax.sharding import PartitionSpec as P
 
             ax = self.runtime.axis_name
-
-            mode = self._exchange.sort_mode(out.shape[0])
-            pack, wide = mode == "pack", mode == "wide"
+            strategy = self.conf.sort_strategy(out.shape[0])
 
             def local_agg(cols, total):
                 valid = jnp.arange(cap) < total[0]
                 combined, nuniq = combine_by_key_cols(
-                    cols, valid, key_words, op, float_payload, wide=wide,
-                    ride_words=self.conf.wide_sort_ride_words, pack=pack)
+                    cols, valid, key_words, op, float_payload, strategy)
                 return combined, nuniq[None]
 
             fn = jax.jit(shard_map(
@@ -1314,40 +1309,17 @@ class ShuffleManager:
             from jax.sharding import PartitionSpec as P
 
             ax = self.runtime.axis_name
-
-            from sparkrdma_tpu.kernels.merge_sort import (merge_sort_cols,
-                                                          supports_fast_sort)
-            from sparkrdma_tpu.kernels.sort import packed_lexsort_cols
-            from sparkrdma_tpu.kernels.wide_sort import sort_wide_cols
-
-            fast = (self.conf.fast_sort
-                    and not self.conf.stable_key_sort
-                    and supports_fast_sort(cap, self.conf.fast_sort_run))
-            mode = self._exchange.sort_mode(w)
-            pack, wide = mode == "pack", mode == "wide"
+            strategy = self.conf.sort_strategy(w)
 
             def local_sort(cols, total):
                 valid = jnp.arange(cap) < total[0]
-                if fast:   # same contract note as the fused tail
-                    return merge_sort_cols(
-                        cols, valid, run=self.conf.fast_sort_run,
-                        interpret=self._exchange.interpret)
-                if pack:
-                    return packed_lexsort_cols(
-                        cols, key_words, valid,
-                        stable=self.conf.stable_key_sort)
-                if wide:
-                    return sort_wide_cols(
-                        cols, key_words, valid,
-                        ride_words=self.conf.wide_sort_ride_words)
-                return lexsort_cols(cols, key_words, valid,
-                                    stable=self.conf.stable_key_sort)
+                return sort_by_key_cols(cols, key_words, valid, strategy,
+                                        stable=self.conf.stable_key_sort)
 
             fn = jax.jit(shard_map(
                 local_sort, mesh=self.runtime.mesh,
                 in_specs=(P(None, ax), P(ax)),
                 out_specs=P(None, ax),
-                check_vma=not fast,   # pallas kernels defeat VMA typing
             ))
             self._sort_cache[key] = fn
             self.metrics.counter("exchange.programs_built.sort").inc()

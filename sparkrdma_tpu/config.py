@@ -43,6 +43,7 @@ DEFAULT_KEY_WORDS = 2
 DEFAULT_VAL_WORDS = 2
 
 
+
 def _parse_prealloc(spec: str) -> Dict[int, int]:
     """Parse a ``"records:count,records:count"`` prealloc spec.
 
@@ -111,10 +112,7 @@ class ShuffleConf:
     #: Use "fine" for stable-geometry workloads (a bench or production
     #: job repeating one shuffle shape) where padding costs real passes;
     #: keep "pow2" when shuffle sizes vary call to call, or every
-    #: slightly-different size recompiles its own program. Interaction:
-    #: fine classes rarely produce the power-of-two out_capacity the
-    #: opt-in fast_sort requires, so fast_sort usually falls back to
-    #: lax.sort under "fine".
+    #: slightly-different size recompiles its own program.
     geometry_classes: str = "pow2"
 
     # --- map-side combine + pushdown (pre-exchange reduction) ---
@@ -184,30 +182,13 @@ class ShuffleConf:
     plan_broadcast_records: int = 4096
 
     # --- reduce-side sort ---
-    #: use the Pallas merge-path sort for fused key-ordering when the
-    #: geometry allows (power-of-two output >= 2 runs). It orders by the
-    #: FULL record (key words first, payload words break ties) and is
-    #: not stable (and requires a power-of-two output capacity — see
-    #: geometry_classes). Default OFF: measured on v5e at 16M x 16B records the
-    #: kernel's in-VMEM merge network (~40ms/stage) loses to lax.sort's
-    #: own fused stages (~6.6ms/doubling; scripts/profile_sweep.py
-    #: mergepath) — XLA's
-    #: sort is already near the bitonic bandwidth floor on this
-    #: hardware. The kernel is kept correct + tested as the scaffold for
-    #: later-generation tuning; opt in to measure.
-    fast_sort: bool = False
-    #: initial run length for the merge-path sort (power of two). The
-    #: default suits real record counts; tests lower it to exercise the
-    #: fast path at CPU-mesh sizes.
-    fast_sort_run: int = 1 << 15
     #: keep arrival order within equal keys on key-ordered reads, and
     #: within a partition on unordered reads. Spark's sortByKey and
     #: repartition contracts do NOT promise this (so the default rides
     #: the cheaper unstable networks — the reduce-side key sort, and
-    #: the map-side bucket sort of unordered, unaggregated reads — and
-    #: permits fast_sort); turn on for callers that layered meaning
-    #: onto arrival order. Wide records (the key+index path) are stable
-    #: either way.
+    #: the map-side bucket sort of unordered, unaggregated reads); turn
+    #: on for callers that layered meaning onto arrival order. Wide
+    #: records (the key+index path) are stable either way.
     stable_key_sort: bool = False
 
     #: payload width (in uint32 words) at or above which key-ordering
@@ -519,11 +500,6 @@ class ShuffleConf:
                              "no retries)")
         if self.transport not in ("xla", "pallas_ring", "hierarchical"):
             raise ValueError(f"unknown transport {self.transport!r}")
-        if (self.fast_sort_run < 128
-                or self.fast_sort_run & (self.fast_sort_run - 1)):
-            raise ValueError(
-                "fast_sort_run must be a power of two >= 128 (the "
-                f"lane-width tile minimum), got {self.fast_sort_run}")
         if self.hierarchy_hosts < 0:
             raise ValueError("hierarchy_hosts must be >= 0")
         if self.map_side_combine not in ("auto", "on", "off"):
@@ -649,6 +625,24 @@ class ShuffleConf:
     def slot_bytes(self) -> int:
         """Bytes of one (src,dst) slot — comparable to maxAggBlock."""
         return self.slot_records * self.record_words * 4
+
+    def sort_strategy(self, record_words: int) -> "SortStrategy":
+        """THE precedence rule for full-record sorts of ``record_words``
+        words: pack > wide > plain, each at its payload threshold (0
+        turns a rung off). Every sort of a record batch — the fused
+        tail, the map-side bucket, the combine, group, join, densify and
+        filter compactions — runs the strategy chosen here."""
+        # local import: config stays importable without jax
+        from sparkrdma_tpu.kernels.sort import SortStrategy
+
+        payload = record_words - self.key_words
+        if self.pack_sort_min_payload and \
+                payload >= self.pack_sort_min_payload:
+            return SortStrategy("pack")
+        if self.wide_sort_min_payload and \
+                payload >= self.wide_sort_min_payload:
+            return SortStrategy("wide", self.wide_sort_ride_words)
+        return SortStrategy()
 
     def prealloc_classes(self) -> Dict[int, int]:
         return _parse_prealloc(self.prealloc)

@@ -75,12 +75,12 @@ from sparkrdma_tpu.kernels.bucketing import (_UNROLL_LIMIT, bucket_records,
                                              fill_round_slots,
                                              fill_round_slots_dest_major,
                                              histogram_pids)
+from sparkrdma_tpu.kernels.sort import sort_by_key_cols, sort_by_lead_cols
 
 from sparkrdma_tpu.obs.metrics import MetricsRegistry
 from sparkrdma_tpu.obs.stats import ExchangeRecord, ShuffleReadStats
 from sparkrdma_tpu.obs.timeline import NULL_TIMELINE, EventTimeline
 from sparkrdma_tpu.obs.watchdog import StallWatchdog
-from sparkrdma_tpu.runtime.mesh import mesh_interpret
 from sparkrdma_tpu.utils.profiling import annotate, device_phase, phase
 
 
@@ -212,10 +212,6 @@ class ShuffleExchange:
         self.axis_name = axis_name
         self.conf = conf or ShuffleConf()
         self.mesh_size = int(mesh.shape[axis_name])
-        #: Pallas kernels of this exchange (ring transport, merge sort)
-        #: run interpreted: follows the mesh's devices, never the
-        #: default backend
-        self.interpret = mesh_interpret(mesh)
         # multi-tenant service identity: spans carry it, exec-cache and
         # collective-id keys fold it in (two tenants' identically-shaped
         # exchanges must not alias), and the account meters HBM buffers
@@ -648,19 +644,6 @@ class ShuffleExchange:
 
         return a2a
 
-    def _uses_fast_sort(self, out_capacity: int, sort_key_words: int,
-                        aggregator: str) -> bool:
-        """Will the fused tail run the Pallas merge-path sort? (Programs
-        embedding it must disable vma checking, like the ring transport —
-        pallas kernels mix varying refs with unvarying grid indices.)"""
-        from sparkrdma_tpu.kernels.merge_sort import supports_fast_sort
-
-        return (bool(sort_key_words) and not aggregator
-                and self.conf.fast_sort
-                and not self.conf.stable_key_sort  # kernel is unstable
-                and supports_fast_sort(out_capacity,
-                                       self.conf.fast_sort_run))
-
     def _fuse_tail(self, out, total, out_capacity, sort_key_words,
                    aggregator, float_payload, tight_out=False):
         """Optional fused reduce-side stages (sort / combine-by-key).
@@ -669,75 +652,28 @@ class ShuffleExchange:
         full (totals == out_capacity), so the sort can drop its
         validity lead operand — one fewer array through the comparator
         network."""
-        mode = self.sort_mode(out.shape[0])
+        strategy = self.conf.sort_strategy(out.shape[0])
         if aggregator:
             from sparkrdma_tpu.kernels.aggregate import combine_by_key_cols
 
             valid = jnp.arange(out_capacity) < total
             out, total = combine_by_key_cols(
                 out, valid, self.conf.key_words, aggregator, float_payload,
-                wide=(mode == "wide"),
-                ride_words=self.conf.wide_sort_ride_words,
-                pack=(mode == "pack"))
+                strategy)
         elif sort_key_words:
-            from sparkrdma_tpu.kernels.merge_sort import merge_sort_cols
-            from sparkrdma_tpu.kernels.sort import (lexsort_cols,
-                                                    packed_lexsort_cols)
-            from sparkrdma_tpu.kernels.wide_sort import sort_wide_cols
-
             valid = (None if tight_out
                      else jnp.arange(out_capacity) < total)
-            if self._uses_fast_sort(out_capacity, sort_key_words,
-                                    aggregator):
-                # Pallas merge-path sort: full-record order (sorted by
-                # the key words; payload words break ties), not stable —
-                # the ExternalSorter contract Spark actually gives for
-                # sortByKey. Stable arrival order within equal keys is
-                # opt-in via conf.stable_key_sort (which disables this
-                # kernel and the unstable fallback below).
-                out = merge_sort_cols(out, valid,
-                                      run=self.conf.fast_sort_run,
-                                      interpret=self.interpret)
-            elif mode == "pack":
-                out = packed_lexsort_cols(
-                    out, sort_key_words, valid,
-                    stable=self.conf.stable_key_sort)
-            elif mode == "wide":
-                out = sort_wide_cols(out, sort_key_words, valid,
-                                     ride_words=self.conf.wide_sort_ride_words)
-            else:
-                # key-ordering only: Spark's sortByKey promises no
-                # secondary order, so the cheaper unstable network is
-                # contract-accurate by default; stable_key_sort restores
-                # arrival-order ties for callers that need them
-                out = lexsort_cols(out, sort_key_words, valid,
+            # key-ordering only: Spark's sortByKey promises no secondary
+            # order, so the cheaper unstable network is contract-accurate
+            # by default; stable_key_sort restores arrival-order ties
+            out = sort_by_key_cols(out, sort_key_words, valid, strategy,
                                    stable=self.conf.stable_key_sort)
         return out, total
 
-    def _wide_sort(self, record_words: int) -> bool:
-        """Payload wide enough for the key+index sort + placement path?
-        (Only reached when packing is off — see :meth:`sort_mode`.)"""
-        t = self.conf.wide_sort_min_payload
-        return bool(t) and record_words - self.conf.key_words >= t
-
-    def _pack_sort(self, record_words: int) -> bool:
-        """Payload wide enough for u64 operand packing? Takes precedence
-        over the ride/gather wide path (round-5 measured winner)."""
-        t = self.conf.pack_sort_min_payload
-        return bool(t) and record_words - self.conf.key_words >= t
-
     def sort_mode(self, record_words: int) -> str:
-        """THE precedence rule for full-record sorts at this geometry:
-        ``"pack"`` (u64 operand packing) > ``"wide"`` (key+index sort +
-        gather placement) > ``"plain"`` (monolithic variadic sort).
-        Every site that picks a sort strategy — fused tail, map-side
-        bucket, combine/group/densify/filter compactions — asks here,
-        so the rule cannot silently diverge between paths."""
-        if self._pack_sort(record_words):
-            return "pack"
-        if self._wide_sort(record_words):
-            return "wide"
-        return "plain"
+        """The name of the sort strategy at this record width
+        (:meth:`ShuffleConf.sort_strategy`), for reports."""
+        return self.conf.sort_strategy(record_words).kind
 
     # ------------------------------------------------------------------
     # map-side front half (shared by both regimes)
@@ -769,13 +705,11 @@ class ShuffleExchange:
                              jnp.int32(num_parts))
         recs = (records if kw_idx is None
                 else jnp.take(records, kw_idx, axis=0))
-        mode = self.sort_mode(recs.shape[0])
+        strategy = self.conf.sort_strategy(recs.shape[0])
         if combine:
             sr, spids, _ = map_side_combine_cols(
                 recs, pids, num_parts, self.conf.key_words, aggregator,
-                float_payload, wide=(mode == "wide"),
-                ride_words=self.conf.wide_sort_ride_words,
-                pack=(mode == "pack"))
+                float_payload, strategy)
             counts, offs = bucket_sorted_counts(spids, num_parts)
             return sr, counts, offs
         # bucket_records' num_parts==1 shortcut skips the histogram (it
@@ -783,11 +717,8 @@ class ShuffleExchange:
         # must still be counted OUT, so bucket over 2 partitions and
         # slice the real one back (a no-op slice otherwise)
         np_eff = num_parts if (num_parts > 1 or row_filter is None) else 2
-        sr, counts, offs = bucket_records(
-            recs, pids, np_eff,
-            wide=(mode == "wide"),
-            ride_words=self.conf.wide_sort_ride_words,
-            pack=(mode == "pack"), stable=stable)
+        sr, counts, offs = bucket_records(recs, pids, np_eff, strategy,
+                                          stable)
         return sr, counts[:num_parts], offs[:num_parts]
 
     # ------------------------------------------------------------------
@@ -864,25 +795,22 @@ class ShuffleExchange:
                 # with the multi-chip paths.
                 from sparkrdma_tpu.kernels.aggregate import (
                     combine_by_key_cols)
-                from sparkrdma_tpu.kernels.sort import sort_by_lead_cols
 
                 n_local = records.shape[1]
                 keep = (row_filter(records) if row_filter is not None
                         else None)
                 out = (records if kw_idx is None
                        else jnp.take(records, kw_idx, axis=0))
+                strategy = self.conf.sort_strategy(out.shape[0])
                 if combine:
                     # map-side == reduce-side here (single source), so
                     # one combine pass subsumes both the filter compact
                     # and the fused tail; dropped rows are just invalid
-                    mode = self.sort_mode(out.shape[0])
                     valid = (keep if keep is not None
                              else jnp.ones((n_local,), bool))
                     out, total = combine_by_key_cols(
                         out, valid, self.conf.key_words, aggregator,
-                        float_payload, wide=(mode == "wide"),
-                        ride_words=self.conf.wide_sort_ride_words,
-                        pack=(mode == "pack"))
+                        float_payload, strategy)
                     wire = total
                     if out_capacity != n_local:
                         out = jnp.pad(
@@ -892,9 +820,8 @@ class ShuffleExchange:
                     if keep is not None:
                         # stable validity-lead compact: surviving rows
                         # to the front in arrival order, zeroed tail
-                        mode = self.sort_mode(out.shape[0])
-                        out = sort_by_lead_cols(
-                            out, (~keep).astype(jnp.uint32), mode)
+                        _, out = sort_by_lead_cols(
+                            out, (~keep).astype(jnp.uint32), strategy)
                         total = jnp.sum(keep).astype(jnp.int32)
                         live = (jnp.arange(n_local) < total)[None, :]
                         out = out * live.astype(out.dtype)
@@ -1031,12 +958,9 @@ class ShuffleExchange:
                 mesh=self.mesh,
                 in_specs=tuple(in_specs),
                 out_specs=(P(None, ax), P(ax), P(ax)),
-                # VMA inference cannot type pallas kernels (ring
-                # transport's device-id arithmetic, merge-sort's grid
-                # indices); pure-XLA programs keep the check
-                check_vma=(self.transport() == "xla"
-                           and not self._uses_fast_sort(
-                               out_capacity, sort_key_words, aggregator)),
+                # VMA inference cannot type the ring transport's
+                # device-id arithmetic; pure-XLA programs keep the check
+                check_vma=(self.transport() == "xla"),
             ),
             donate_argnums=((1,) if donate_out else ()),
         )
@@ -1270,8 +1194,6 @@ class ShuffleExchange:
             local_tail, mesh=self.mesh,
             in_specs=(P(None, ax), P(ax)),
             out_specs=(P(None, ax), P(ax)),
-            check_vma=not self._uses_fast_sort(out_capacity,
-                                               sort_key_words, aggregator),
         ))
 
     def _exchange_streaming(self, records, partitioner, plan, num_parts,
@@ -1616,8 +1538,8 @@ class ShuffleExchange:
                 plan.num_rounds == 1 and self.mesh_size == 1)):
             return
         w_eff = len(keep_words) if keep_words is not None else w
-        unstable = (not stable and not use_combine
-                    and self.sort_mode(w_eff) != "wide")
+        unstable = not (use_combine or self.conf.sort_strategy(
+            w_eff).sorts_stably(stable))
         self.metrics.counter("exchange.bucket_sort.unstable" if unstable
                              else "exchange.bucket_sort.stable").inc()
 

@@ -35,7 +35,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from sparkrdma_tpu.kernels.sort import lexsort_cols
+from sparkrdma_tpu.kernels.sort import (SortStrategy, sort_by_key_cols,
+                                        sort_by_lead_cols)
 from sparkrdma_tpu.utils.profiling import device_phase
 
 
@@ -67,32 +68,19 @@ def combine_by_key_cols(
     key_words: int,
     op: str = "sum",
     float_payload: bool = False,
-    wide: bool = False,
-    ride_words: int = 0,
-    pack: bool = False,
+    strategy: SortStrategy = SortStrategy(),
 ) -> Tuple[jax.Array, jax.Array]:
     """Reduce payloads of equal keys; return ``(combined, num_unique)``.
 
     ``cols: uint32[W, N]`` with leading ``key_words`` key rows. Output
     keeps shape ``[W, N]``: the first ``num_unique`` columns are unique
     keys (sorted ascending) with reduced payloads; tail is zero padding.
-    ``pack`` routes both sorts through u64 operand packing (round-5
-    winner, kernels/sort.py); ``wide`` through the key+index ride/gather
-    path (the round-4 fallback) — either way wide payloads never meet
-    the >13-operand comparator wall; same output contract.
+    Both sorts move the record words as ``strategy`` says
+    (kernels/sort.py), so wide payloads never meet the >13-operand
+    comparator wall; same output contract under every strategy.
     """
-    w, n = cols.shape
-    if pack:
-        from sparkrdma_tpu.kernels.sort import packed_lexsort_cols
-
-        srt = packed_lexsort_cols(cols, key_words, valid, stable=True)
-    elif wide:
-        from sparkrdma_tpu.kernels.wide_sort import sort_wide_cols
-
-        srt = sort_wide_cols(cols, key_words, valid,
-                             ride_words=ride_words)
-    else:
-        srt = lexsort_cols(cols, key_words, valid)
+    n = cols.shape[1]
+    srt = sort_by_key_cols(cols, key_words, valid, strategy, stable=True)
     nvalid = jnp.sum(valid).astype(jnp.int32)
     in_valid = jnp.arange(n) < nvalid
     keys = srt[:key_words]                       # [kw, N]
@@ -123,34 +111,8 @@ def combine_by_key_cols(
     next_same = jnp.concatenate([same[1:], jnp.zeros((1,), bool)])
     last_of_run = in_valid & ~next_same
     lead = (~last_of_run).astype(jnp.uint8)
-    if pack:
-        from sparkrdma_tpu.kernels.sort import packed_partition_cols
-
-        full = jnp.concatenate([keys, red], axis=0)
-        _, out = packed_partition_cols(full, lead.astype(jnp.uint32),
-                                       stable=True)
-    elif wide:
-        # compact via a (flag, ridden words..., index) sort + one gather
-        # pass instead of riding all W words through the network again
-        from sparkrdma_tpu.kernels.wide_sort import apply_perm
-
-        full = jnp.concatenate([keys, red], axis=0)
-        # ride_words is a PAYLOAD-word budget (sort_wide_cols semantics):
-        # the key words ride for free on top of it, so the measured
-        # 13-operand knee applies uniformly to both wide paths
-        ride = min(key_words + max(0, ride_words), w)
-        idx = lax.iota(jnp.int32, n)
-        operands = (lead,) + tuple(full[i] for i in range(ride)) + (idx,)
-        packed = lax.sort(operands, num_keys=1, is_stable=True)
-        perm = packed[-1]
-        ridden = jnp.stack(packed[1:-1]) if ride else full[:0]
-        placed = apply_perm(full[ride:].T, perm).T
-        out = jnp.concatenate([ridden, placed], axis=0)
-    else:
-        operands = (lead,) + tuple(keys[i] for i in range(key_words)) \
-            + tuple(red[i] for i in range(w - key_words))
-        packed = lax.sort(operands, num_keys=1, is_stable=True)
-        out = jnp.stack(packed[1:])
+    _, out = sort_by_lead_cols(jnp.concatenate([keys, red], axis=0), lead,
+                               strategy)
     live = (jnp.arange(n) < num_unique)[None, :]
     out = out * live.astype(out.dtype)
     return out, num_unique
@@ -164,9 +126,7 @@ def map_side_combine_cols(
     key_words: int,
     op: str = "sum",
     float_payload: bool = False,
-    wide: bool = False,
-    ride_words: int = 0,
-    pack: bool = False,
+    strategy: SortStrategy = SortStrategy(),
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Pre-exchange reduction: collapse duplicate (partition, key) pairs.
 
@@ -196,8 +156,7 @@ def map_side_combine_cols(
         [part_ids.astype(jnp.uint32)[None], records], axis=0)
     valid = (part_ids >= 0) & (part_ids < num_parts)
     combined, num_unique = combine_by_key_cols(
-        cols, valid, 1 + key_words, op, float_payload,
-        wide=wide, ride_words=ride_words, pack=pack)
+        cols, valid, 1 + key_words, op, float_payload, strategy)
     live = jnp.arange(n) < num_unique
     new_pids = jnp.where(live, combined[0].astype(jnp.int32),
                          jnp.int32(num_parts))

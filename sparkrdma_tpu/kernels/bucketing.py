@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from sparkrdma_tpu.kernels.sort import SortStrategy, sort_by_lead_cols
 from sparkrdma_tpu.utils.profiling import device_phase
 
 #: Above this many serially-dependent copies, emit a device loop instead of
@@ -116,35 +117,28 @@ def _outer_histogram(part_ids: jax.Array, num_parts: int) -> jax.Array:
 
 def bucket_records(
     records: jax.Array, part_ids: jax.Array, num_parts: int,
-    wide: bool = False, ride_words: int = 0, pack: bool = False,
-    stable: bool = True
+    strategy: SortStrategy = SortStrategy(), stable: bool = True
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Sort a columnar batch ``[W, N]`` by destination partition.
 
     Returns ``(bucketed [W, N], counts [P], offsets [P])`` where
     ``counts[p]`` is the number of local records bound for partition ``p``
     and ``offsets[p]`` the start of its run — the exact content of Spark's
-    shuffle index file. One fused variadic sort: pid is the key, record
-    word columns ride along as values; counts come from the sorted pid
-    vector (see :func:`histogram_pids`), not a scatter.
+    shuffle index file. One lead-keyed sort
+    (:func:`~sparkrdma_tpu.kernels.sort.sort_by_lead_cols`, moving the
+    record words as ``strategy`` says): pid is the key; counts come from
+    the sorted pid vector (see :func:`histogram_pids`), not a scatter.
 
     ``stable`` (the default) keeps arrival order within a partition. XLA
     makes a sort stable with a hidden s32 iota as its last key, so
     ``stable=False`` drops an operand and a compare key: on the plain
-    branch at ``W = 2`` the sort carries 3 operands instead of 4. Counts,
-    offsets, partition contiguity and which records land in which
-    partition are the same either way; only the order of records within
-    one partition may differ. The wide branch is stable regardless (its
-    index operand is the permutation it places rows by).
-
-    ``pack`` (takes precedence): ride the whole record as u64-PACKED
-    operands — pid + ceil(W/2) operands, no gather pass (round-5
-    measured winner for wide records, kernels/sort.py
-    §packed_lexsort_cols). ``wide``: sort only ``(pid, ride..., index)``
-    and place the remaining words with one gather pass (the round-4
-    fallback, kept for hardware where packing measures worse).
+    strategy at ``W = 2`` the sort carries 3 operands instead of 4.
+    Counts, offsets, partition contiguity and which records land in
+    which partition are the same either way; only the order of records
+    within one partition may differ. The wide strategy is stable
+    regardless (its index operand is the permutation it places rows by).
     """
-    w, n = records.shape
+    n = records.shape[1]
     if num_parts == 1:
         # single destination: the batch IS the one run — no reorder, no
         # histogram (the degenerate case a 1-chip mesh hits on its hot
@@ -154,39 +148,9 @@ def bucket_records(
                 jnp.full((1,), n, jnp.int32),
                 jnp.zeros((1,), jnp.int32))
     part_ids = part_ids.astype(jnp.int32)
-    if pack:
-        from sparkrdma_tpu.kernels.sort import packed_partition_cols
-
-        sorted_ids_u32, bucketed = packed_partition_cols(
-            records, part_ids.astype(jnp.uint32), stable=stable)
-        sorted_ids = sorted_ids_u32.astype(jnp.int32)
-        counts = histogram_pids(part_ids, num_parts, sorted_ids=sorted_ids)
-        offsets = jnp.concatenate(
-            [jnp.zeros((1,), jnp.int32),
-             jnp.cumsum(counts)[:-1].astype(jnp.int32)])
-        return bucketed, counts, offsets
-    if wide:
-        from sparkrdma_tpu.kernels.wide_sort import apply_perm
-
-        ride = max(0, min(ride_words, w))
-        idx = lax.iota(jnp.int32, n)
-        operands = (part_ids,) + tuple(records[i] for i in range(ride)) \
-            + (idx,)
-        out = lax.sort(operands, num_keys=1, is_stable=True)
-        sorted_ids, perm = out[0], out[-1]
-        ridden = jnp.stack(out[1:-1]) if ride else records[:0]
-        placed = apply_perm(records[ride:].T, perm).T
-        bucketed = jnp.concatenate([ridden, placed], axis=0)
-        counts = histogram_pids(part_ids, num_parts, sorted_ids=sorted_ids)
-        offsets = jnp.concatenate(
-            [jnp.zeros((1,), jnp.int32),
-             jnp.cumsum(counts)[:-1].astype(jnp.int32)]
-        )
-        return bucketed, counts, offsets
-    out = lax.sort((part_ids,) + tuple(records[i] for i in range(w)),
-                   num_keys=1, is_stable=stable)
-    bucketed = jnp.stack(out[1:])
-    counts = histogram_pids(part_ids, num_parts, sorted_ids=out[0])
+    sorted_ids, bucketed = sort_by_lead_cols(records, part_ids, strategy,
+                                             stable)
+    counts = histogram_pids(part_ids, num_parts, sorted_ids=sorted_ids)
     offsets = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32), jnp.cumsum(counts)[:-1].astype(jnp.int32)]
     )

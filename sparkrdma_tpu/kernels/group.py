@@ -22,9 +22,10 @@ numbers): run boundaries come from adjacent-equality, run START
 positions are compacted by a single-operand sort (ascending positions
 with an N sentinel for non-starts), counts are adjacent differences of
 the compacted starts, and keys are gathered at start positions instead
-of riding a second full-record sort. Wide records route the one
-full-record sort through kernels/wide_sort.py, so groupByKey never
-meets the 25-operand compile wall.
+of riding a second full-record sort. The one full-record sort moves
+wide records as the conf's sort strategy says (kernels/sort.py
+§sort_by_key_cols), so groupByKey never meets the 25-operand compile
+wall.
 """
 
 from __future__ import annotations
@@ -35,17 +36,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from sparkrdma_tpu.kernels.sort import lexsort_cols
-from sparkrdma_tpu.kernels.wide_sort import sort_wide_cols
+from sparkrdma_tpu.kernels.sort import SortStrategy, sort_by_key_cols
 
 
 def group_runs_cols(
     cols: jax.Array,
     valid: jax.Array,
     key_words: int,
-    wide: bool = False,
-    ride_words: int = 0,
-    pack: bool = False,
+    strategy: SortStrategy = SortStrategy(),
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Key-sort ``cols: uint32[W, N]`` and emit its CSR group table.
 
@@ -62,16 +60,8 @@ def group_runs_cols(
     records always, so there is NO overflow mode here — unlike the join,
     every group fits by construction.
     """
-    w, n = cols.shape
-    if pack:
-        from sparkrdma_tpu.kernels.sort import packed_lexsort_cols
-
-        values = packed_lexsort_cols(cols, key_words, valid, stable=True)
-    elif wide:
-        values = sort_wide_cols(cols, key_words, valid,
-                                ride_words=ride_words)
-    else:
-        values = lexsort_cols(cols, key_words, valid)
+    n = cols.shape[1]
+    values = sort_by_key_cols(cols, key_words, valid, strategy, stable=True)
     total = jnp.sum(valid).astype(jnp.int32)
     pos = jnp.arange(n, dtype=jnp.int32)
     in_valid = pos < total

@@ -11,6 +11,10 @@ the same post-fetch stages run in HBM on fixed-shape arrays:
   multi-word (e.g. 64-bit as hi/lo uint32) key;
 - :func:`merge_sorted_runs` exploits that each source's run arrives already
   key-sorted (when the writer pre-sorts), like Spark's tiered merge.
+- :func:`sort_by_key_cols` and :func:`sort_by_lead_cols` are the only
+  places that tell the record-movement strategies (plain, wide, pack:
+  :class:`SortStrategy`) apart; every full-record sort goes through
+  one of them.
 
 Keys sort lexicographically over their uint32 words, most-significant word
 first — matching TeraSort's byte-lexicographic ordering.
@@ -18,13 +22,35 @@ first — matching TeraSort's byte-lexicographic ordering.
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import enable_x64, lax
 
+from sparkrdma_tpu.kernels.wide_sort import apply_perm, sort_wide_cols
 from sparkrdma_tpu.utils.profiling import device_phase
+
+
+class SortStrategy(NamedTuple):
+    """How a full-record sort moves the record words through the
+    comparator network (:func:`sort_by_key_cols` and
+    :func:`sort_by_lead_cols` carry it out;
+    :meth:`~sparkrdma_tpu.config.ShuffleConf.sort_strategy` chooses it):
+
+    - ``"plain"``: every word rides one variadic ``lax.sort``;
+    - ``"wide"``: the sort keys, ``ride_words`` more words and a row
+      index are sorted, then one gather places the rest;
+    - ``"pack"``: pairs of u32 words ride as u64 operands.
+    """
+
+    kind: str = "plain"
+    ride_words: int = 0
+
+    def sorts_stably(self, stable: bool) -> bool:
+        """Is a sort asked for ``stable`` stable under this strategy?
+        Every wide form is: its index operand breaks ties."""
+        return stable or self.kind == "wide"
 
 
 def _sort_rows(records: jax.Array, num_keys: int,
@@ -178,49 +204,65 @@ def packed_lexsort_cols(
     return jnp.stack(rows)
 
 
-def packed_partition_cols(
-    cols: jax.Array, lead: jax.Array, stable: bool = True
-) -> Tuple[jax.Array, jax.Array]:
-    """Sort full records by a single u32 ``lead`` row (partition id,
-    validity rank, compaction flag...), the whole record riding as
-    packed u64 operands. Returns ``(sorted_lead, sorted_cols)``.
+def sort_by_key_cols(cols: jax.Array, key_words: int,
+                     valid: jax.Array | None, strategy: SortStrategy,
+                     stable: bool) -> jax.Array:
+    """Sort full records ``cols: uint32[W, N]`` by their leading
+    ``key_words`` rows, padding (``valid == False``) to the tail — the
+    key-ordered sort of the fused tail, the combine and the group, with
+    the record movement ``strategy`` chooses
+    (:meth:`~sparkrdma_tpu.config.ShuffleConf.sort_strategy`).
 
-    The shared primitive behind the map-side bucket, the wide
-    re-densification and the rank-keyed filters once packing is on: any
-    "order rows by one computed key" pass becomes lead + ceil(W/2)
-    operands instead of lead + W.
+    ``strategy.ride_words`` counts PAYLOAD words, past the keys, that
+    ride the wide sort as values; the rest are placed by one gather.
+    Plain and pack honour ``stable``; the wide form is stable always,
+    so no output depends on the ride. Adds no phase: the key sort is
+    ``sr_sort_keys`` and a wide placement ``sr_sort_gather``.
     """
-    cols2 = jnp.concatenate([lead[None].astype(jnp.uint32), cols])
-    # the sort outside ``sr_sort_keys``: ordering by a computed lead is
-    # the caller's phase (map-side bucketing, a compaction)
-    out = packed_lexsort_cols.__wrapped__(cols2, 1, stable=stable)
-    return out[0], out[1:]
+    if strategy.kind == "pack":
+        return packed_lexsort_cols(cols, key_words, valid, stable=stable)
+    if strategy.kind == "wide":
+        return sort_wide_cols(cols, key_words, valid,
+                              ride_words=strategy.ride_words)
+    return lexsort_cols(cols, key_words, valid, stable=stable)
 
 
-def sort_by_lead_cols(cols: jax.Array, lead: jax.Array, mode: str,
-                      stable: bool = True) -> jax.Array:
-    """Order full records ``[W, N]`` by a single u32 ``lead`` row
-    (validity flag, partition rank, compaction key...), with the record
-    movement strategy chosen by ``mode`` (the
-    ``ShuffleExchange.sort_mode`` value): ``"pack"`` rides u64-packed,
-    ``"wide"`` sorts ``(lead, index)`` and places by one gather,
-    ``"plain"`` rides every word. THE one implementation of lead-keyed
-    compaction — the join filler strips, re-densification and the
-    skew-split range filter all call here, so a strategy fix applies
-    everywhere at once.
+def sort_by_lead_cols(cols: jax.Array, lead: jax.Array,
+                      strategy: SortStrategy, stable: bool = True
+                      ) -> Tuple[jax.Array, jax.Array]:
+    """Order full records ``cols: [W, N]`` by one ``lead`` row
+    (partition id, validity flag, compaction rank...); return
+    ``(sorted_lead, sorted_cols)``. THE one lead-keyed sort: the
+    map-side bucket, the combine compaction, the join's key sort and
+    every filter and densify compaction call here.
+
+    The lead sorts in its own dtype (partition ids stay s32); packing
+    sorts it as u32, the width its operands share, and returns it in
+    its own dtype. ``strategy.ride_words`` counts RECORD words, from
+    word 0, that ride the wide sort beside the lead and a row index;
+    the rest are placed by one gather. Plain and pack honour
+    ``stable``; the wide form is stable always
+    (:meth:`~sparkrdma_tpu.config.SortStrategy.sorts_stably`). Adds no
+    phase: the sort is the caller's (bucketing, a compaction).
     """
-    lead = lead.astype(jnp.uint32)
-    if mode == "pack":
-        return packed_partition_cols(cols, lead, stable=stable)[1]
-    if mode == "wide":
-        from sparkrdma_tpu.kernels.wide_sort import apply_perm
-
-        idx = lax.iota(jnp.int32, cols.shape[1])
-        srt = lax.sort((lead, idx), num_keys=1, is_stable=stable)
-        return apply_perm(cols.T, srt[-1]).T
-    out = lax.sort((lead,) + tuple(cols[i] for i in range(cols.shape[0])),
-                   num_keys=1, is_stable=stable)
-    return jnp.stack(out[1:])
+    w, n = cols.shape
+    is_stable = strategy.sorts_stably(stable)
+    if strategy.kind == "pack":
+        both = jnp.concatenate([lead[None].astype(jnp.uint32), cols])
+        # unwrapped: outside ``sr_sort_keys``, in the caller's phase
+        out = packed_lexsort_cols.__wrapped__(both, 1, stable=is_stable)
+        return out[0].astype(lead.dtype), out[1:]
+    if strategy.kind == "wide":
+        ride = min(strategy.ride_words, w)
+        out = lax.sort((lead,) + tuple(cols[i] for i in range(ride))
+                       + (lax.iota(jnp.int32, n),),
+                       num_keys=1, is_stable=is_stable)
+        ridden = jnp.stack(out[1:-1]) if ride else cols[:0]
+        placed = apply_perm(cols[ride:].T, out[-1]).T
+        return out[0], jnp.concatenate([ridden, placed], axis=0)
+    out = lax.sort((lead,) + tuple(cols[i] for i in range(w)),
+                   num_keys=1, is_stable=is_stable)
+    return out[0], jnp.stack(out[1:])
 
 
 def merge_sorted_runs(
@@ -232,8 +274,6 @@ def merge_sorted_runs(
     ``run_counts: int32[S]``. Returns ``(merged: [S*C, W], total: int32)``
     with padding at the tail. XLA has no efficient k-way merge primitive, so
     this flattens and re-sorts — O(n log n) but fully parallel on the VPU.
-    The Pallas true-merge exists (``kernels/merge_sort.py``) but measured
-    slower than ``lax.sort`` on v5e — see its MEASURED STATUS note.
     """
     s, c, w = runs.shape
     flat = runs.reshape(s * c, w)
@@ -244,6 +284,6 @@ def merge_sorted_runs(
     return merged, total
 
 
-__all__ = ["compact", "lexsort_records", "lexsort_cols",
-           "packed_lexsort_cols", "packed_partition_cols",
-           "sort_by_lead_cols", "merge_sorted_runs"]
+__all__ = ["SortStrategy", "compact", "lexsort_records", "lexsort_cols",
+           "packed_lexsort_cols", "sort_by_key_cols", "sort_by_lead_cols",
+           "merge_sorted_runs"]

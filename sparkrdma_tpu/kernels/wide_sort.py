@@ -108,8 +108,8 @@ def sort_wide_cols(
     two cost curves cross (v5e, 16M records): riding costs ~10-16ms per
     word up to ~13 total operands then turns sharply superlinear
     (13 operands: 202ms, 25: 630ms), while the gather pass is
-    expensive but one-shot. The caller picks the measured optimum
-    (``ShuffleConf.wide_sort_ride_words``).
+    expensive but one-shot. The conf picks the measured optimum
+    (``ShuffleConf.sort_strategy``).
 
     Drop-in for :func:`~sparkrdma_tpu.kernels.sort.lexsort_cols` (same
     contract: stable, padding to the tail) for wide records. In a

@@ -7,6 +7,7 @@ from sparkrdma_tpu.kernels import (bucket_records, compact_segments,
                                    fill_round_slots,
                                    fill_round_slots_dest_major)
 from sparkrdma_tpu.kernels.bucketing import histogram_pids
+from sparkrdma_tpu.kernels.sort import SortStrategy
 
 
 def _cols(rows):
@@ -38,8 +39,9 @@ def _canon(rows):
     return rows[np.lexsort(tuple(rows[:, c] for c in range(rows.shape[1])))]
 
 
-@pytest.mark.parametrize("pack", [False, True], ids=["plain", "pack"])
-def test_bucket_records_unstable_keeps_index_and_multisets(rng, pack):
+@pytest.mark.parametrize("strategy", [SortStrategy(), SortStrategy("pack")],
+                         ids=["plain", "pack"])
+def test_bucket_records_unstable_keeps_index_and_multisets(rng, strategy):
     """``stable=False`` may reorder records within a partition and
     nothing else: counts and offsets exact, every partition's run holds
     its records as a multiset, and a row filter's sentinel pid
@@ -50,7 +52,7 @@ def test_bucket_records_unstable_keeps_index_and_multisets(rng, pack):
     dropped = rng.random(n) < 0.2
     pids_np[dropped] = p
     sr, counts, offs = bucket_records(_cols(rows), jnp.asarray(pids_np), p,
-                                      pack=pack, stable=False)
+                                      strategy, stable=False)
     np_counts = np.bincount(pids_np[~dropped], minlength=p)
     np.testing.assert_array_equal(np.asarray(counts), np_counts)
     np.testing.assert_array_equal(
@@ -66,7 +68,7 @@ def test_bucket_records_unstable_keeps_index_and_multisets(rng, pack):
                                   _canon(rows[dropped]))
     # the index half is the stable form's, bit for bit
     _, s_counts, s_offs = bucket_records(
-        _cols(rows), jnp.asarray(pids_np), p, pack=pack)
+        _cols(rows), jnp.asarray(pids_np), p, strategy)
     np.testing.assert_array_equal(np.asarray(counts), np.asarray(s_counts))
     np.testing.assert_array_equal(np.asarray(offs), np.asarray(s_offs))
 

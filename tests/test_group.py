@@ -14,6 +14,7 @@ import pytest
 
 from sparkrdma_tpu.config import ShuffleConf
 from sparkrdma_tpu.kernels.group import cogroup_tables, group_runs_cols
+from sparkrdma_tpu.kernels.sort import SortStrategy
 
 
 def np_groups(rows, kw):
@@ -41,8 +42,8 @@ def test_group_runs_cols_matches_numpy(rng, w, wide):
     rows[: n // 2, :kw] = [1, 7]                  # hot key: half the rows
     valid = rng.random(n) < 0.9
     values, groups, n_groups, total = group_runs_cols(
-        jnp.asarray(rows.T), jnp.asarray(valid), kw, wide=wide,
-        ride_words=3)
+        jnp.asarray(rows.T), jnp.asarray(valid), kw,
+        SortStrategy("wide", 3) if wide else SortStrategy())
     values, groups = np.asarray(values), np.asarray(groups)
     ng, tot = int(n_groups), int(total)
     ref = np_groups(rows[valid], kw)

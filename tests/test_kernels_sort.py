@@ -3,6 +3,8 @@ import numpy as np
 import pytest
 
 from sparkrdma_tpu.kernels import compact, lexsort_records, merge_sorted_runs
+from sparkrdma_tpu.kernels.sort import (SortStrategy, sort_by_key_cols,
+                                        sort_by_lead_cols)
 
 
 def make_records(rng, n, key_words=2, val_words=2):
@@ -142,3 +144,45 @@ def test_packed_lexsort_leaves_x64_flag_off():
     x = jnp.zeros((4, 128), jnp.uint32)
     jax.jit(lambda c: packed_lexsort_cols(c, 2))(x)
     assert not jax.config.jax_enable_x64
+
+
+# --- the two entry points: one result whatever the strategy ----------
+
+STRATEGIES = {
+    "plain": SortStrategy(),
+    "wide_ride0": SortStrategy("wide"),
+    "wide_ride3": SortStrategy("wide", 3),
+    "pack": SortStrategy("pack"),
+}
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["no_mask", "mask"])
+@pytest.mark.parametrize("name", sorted(STRATEGIES))
+def test_sort_by_key_cols_strategies_agree(rng, name, masked):
+    """A stable key sort equals numpy's stable lexsort under every
+    strategy: invalid rows to the tail, equal keys in arrival order."""
+    n, w, kw = 512, 9, 2
+    cols = rng.integers(0, 2**32, size=(w, n), dtype=np.uint32)
+    cols[0] = rng.integers(0, 3, size=n)          # many equal keys
+    cols[1] = rng.integers(0, 8, size=n)
+    valid = rng.random(n) < 0.8 if masked else np.ones(n, bool)
+    got = np.asarray(sort_by_key_cols(
+        jnp.asarray(cols), kw, jnp.asarray(valid) if masked else None,
+        STRATEGIES[name], stable=True))
+    order = np.lexsort((cols[1], cols[0], ~valid))
+    np.testing.assert_array_equal(got, cols[:, order])
+
+
+@pytest.mark.parametrize("name", ["plain", "wide_ride3", "pack"])
+def test_sort_by_lead_cols_strategies_agree(rng, name):
+    """The lead comes back sorted in its own dtype, and the rows follow
+    it in a stable order, under every strategy."""
+    n, w = 512, 5
+    cols = rng.integers(0, 2**32, size=(w, n), dtype=np.uint32)
+    lead = rng.integers(0, 6, size=n).astype(np.int32)
+    got_lead, got = sort_by_lead_cols(jnp.asarray(cols), jnp.asarray(lead),
+                                      STRATEGIES[name])
+    order = np.argsort(lead, kind="stable")
+    assert got_lead.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got_lead), lead[order])
+    np.testing.assert_array_equal(np.asarray(got), cols[:, order])

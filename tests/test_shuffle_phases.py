@@ -245,6 +245,48 @@ def test_manager_jobs_count_their_bucket_sort(tmp_path, job, kind):
     assert counts[kind] == 2 and sum(counts.values()) == 2, counts
 
 
+#: each cell's sort form: its conf, partitions, key-sort words, the
+#: phase its sort runs in, and the operand types of that sort as the
+#: chip's trace names them. TeraSort's tight tail sorts 3 key words
+#: and the wide form's index; repartition(256)'s unordered bucket sort
+#: carries the s32 partition id and the 2 record words, no index.
+CELL_FORMS = {
+    "terasort": (dict(key_words=3, val_words=22, pack_sort_min_payload=0,
+                      wide_sort_ride_words=0), 1, 3, "sr_sort_keys",
+                 "(u32[{n}], u32[{n}], u32[{n}], s32[{n}])"),
+    "repartition": (dict(key_words=2, val_words=0), 16, 0, "sr_bucket",
+                    "(s32[{n}], u32[{n}], u32[{n}])"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_FORMS))
+def test_cell_sort_forms_pinned(runtime, cell):
+    """The benchmark cells' fused programs on one device, at a small N:
+    the sort keeps the operand types the cell's trace reads, and a
+    wide placement gathers all 22 payload words."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    conf_kw, parts, skw, scope, form = CELL_FORMS[cell]
+    n = 1 << 12
+    conf = ShuffleConf(slot_records=n, geometry_classes="fine", **conf_kw)
+    w = conf.record_words
+    mesh = Mesh(np.asarray(runtime.mesh.devices).reshape(-1)[:1], ("s",))
+    ex = ShuffleExchange(mesh, "s", conf)
+    part = (range_partitioner(np.zeros((0, 3), np.uint32), 3)
+            if parts == 1 else hash_partitioner(parts))
+    fn = ex._build_exec(parts, n, 1, n, w, part, sort_key_words=skw,
+                        tight_out=parts == 1, stable_buckets=False)
+    x = jax.ShapeDtypeStruct((w, n), np.uint32,
+                             sharding=NamedSharding(mesh, P(None, "s")))
+    hlo = re.sub(r"\{[0-9,]*\}", "", fn.lower(x).compile().as_text())
+    sorts = [ln for ln in hlo.splitlines()
+             if re.search(r"\bsort\(", ln) and f"/{scope}/" in ln]
+    assert len(sorts) == 1 and f"{form.format(n=n)} sort(" in sorts[0], \
+        sorts
+    if parts == 1:
+        assert re.search(rf"u32\[{n},22\] \w+\(.*/sr_sort_gather/", hlo)
+
+
 #: host spans of one range-partitioned job, and the span each one must
 #: lie inside
 NESTED = {

@@ -2,12 +2,10 @@
 caught without a chip. Nothing here runs; each test compiles.
 
 Kernels that passed every interpret-mode test were refused by the
-chip's compiler (the ring's per-destination slice at W % 8 != 0, the
-merge sort's scoped VMEM), so these compile the main path's kernels at
-its real record widths: the fused ring exchange at W=13/25 on a 4-chip
-mesh, ``merge_sort_cols`` at W=8/25 (and, marked slow, at 4M records
-with its own tile choice), and the one-chip TeraSort exchange+sort step
-at 100-byte records.
+chip's compiler (the ring's per-destination slice at W % 8 != 0), so
+these compile the main path's kernels at its real record widths: the
+fused ring exchange at W=13/25 on a 4-chip mesh, and the one-chip
+TeraSort exchange+sort step at 100-byte records.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU library, and every
@@ -22,8 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
-                          SingleDeviceSharding)
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 @pytest.fixture(scope="module")
@@ -68,35 +65,6 @@ def test_fused_ring_exchange_compiles(topo, w):
     assert "tpu_custom_call" in _compile(fn, slots).as_text()
 
 
-@pytest.mark.parametrize("w", [8, 25])
-def test_merge_sort_compiles(topo, w):
-    """W=25 fills 25 of the 32 rows of its 8-row tiles: the kernel's
-    window slices must still be tile-aligned. Smallest working tile —
-    Mosaic's compile time grows with tile x W (W=25: ~30 s even here)."""
-    from sparkrdma_tpu.kernels.merge_sort import merge_sort_cols
-
-    cols = jax.ShapeDtypeStruct((w, 1 << 12), jnp.uint32,
-                                sharding=SingleDeviceSharding(
-                                    topo.devices[0]))
-    compiled = _compile(lambda c: merge_sort_cols(
-        c, run=1 << 10, tile=256, interpret=False), cols)
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-@pytest.mark.slow
-def test_merge_sort_auto_tile_fits_vmem(topo):
-    """The tile ``_pick_tile`` chooses must fit the kernel's real scoped
-    VMEM: W=8 at 4M records once asked for more than Mosaic's limit.
-    Slow: the full-size tile takes over a minute to compile."""
-    from sparkrdma_tpu.kernels.merge_sort import merge_sort_cols
-
-    cols = jax.ShapeDtypeStruct((8, 1 << 22), jnp.uint32,
-                                sharding=SingleDeviceSharding(
-                                    topo.devices[0]))
-    compiled = _compile(lambda c: merge_sort_cols(c, interpret=False), cols)
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 def test_one_chip_terasort_step_compiles(topo):
     """chip_smoke Phase A's program — the degenerate one-chip exchange
     plus the wide fused sort at W=25 — at a small tile-aligned N."""
@@ -109,7 +77,7 @@ def test_one_chip_terasort_step_compiles(topo):
     conf = ShuffleConf(slot_records=n, val_words=23, geometry_classes="fine",
                        pack_sort_min_payload=0, wide_sort_ride_words=0)
     ex = ShuffleExchange(mesh, "shuffle", conf)
-    assert not ex.interpret and ex.sort_mode(25) == "wide"
+    assert ex.sort_mode(25) == "wide"
     fn = ex._build_exec(num_parts=1, capacity=n, num_rounds=1,
                         out_capacity=n, record_words=25,
                         partitioner=range_partitioner(

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from sparkrdma_tpu.kernels.sort import lexsort_cols
+from sparkrdma_tpu.kernels.sort import SortStrategy, lexsort_cols
 from sparkrdma_tpu.kernels.wide_sort import (apply_perm, sort_perm,
                                              sort_wide_cols)
 
@@ -78,7 +78,7 @@ def test_jittable_under_jit(rng):
 
 
 def test_combine_by_key_wide_parity(rng):
-    """wide=True combine must equal wide=False on identical input."""
+    """The wide combine must equal the plain one on identical input."""
     from sparkrdma_tpu.kernels.aggregate import combine_by_key_cols
 
     n = 1024
@@ -92,7 +92,7 @@ def test_combine_by_key_wide_parity(rng):
                                         jnp.asarray(valid), 2, op)
         got, ngot = combine_by_key_cols(jnp.asarray(cols),
                                         jnp.asarray(valid), 2, op,
-                                        wide=True)
+                                        strategy=SortStrategy("wide"))
         assert int(nref) == int(ngot)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
@@ -166,7 +166,7 @@ def test_combine_wide_ride_parity(rng):
     ref, nref = combine_by_key_cols(jnp.asarray(cols), jnp.asarray(valid),
                                     2, "sum")
     got, ngot = combine_by_key_cols(jnp.asarray(cols), jnp.asarray(valid),
-                                    2, "sum", wide=True, ride_words=4)
+                                    2, "sum", strategy=SortStrategy("wide", 4))
     assert int(nref) == int(ngot)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
@@ -182,7 +182,7 @@ def test_wide_verbs_end_to_end(rng):
 
     conf = ShuffleConf(slot_records=512, val_words=23)
     with ShuffleManager(MeshRuntime(conf), conf) as m:
-        assert m._exchange._pack_sort(conf.record_words)
+        assert conf.sort_strategy(conf.record_words).kind == "pack"
         n = 8 * 32
         base = rng.integers(1, 2**31, size=(n // 2, 25), dtype=np.uint32)
         x = np.concatenate([base, base])          # every row twice
