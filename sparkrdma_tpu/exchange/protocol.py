@@ -70,6 +70,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from sparkrdma_tpu.config import (ShuffleConf, size_class,
                                   size_class_fine)
 from sparkrdma_tpu.kernels.bucketing import (_UNROLL_LIMIT, bucket_records,
+                                             bucket_records_hash_keyed,
                                              bucket_sorted_counts,
                                              compact_segments,
                                              fill_round_slots,
@@ -691,7 +692,9 @@ class ShuffleExchange:
         (partition, key) sort already IS the bucketing sort, so its
         compacted counts come from one :func:`bucket_sorted_counts`
         histogram — or the bucketing sort, stable only where
-        :meth:`exchange` found a read that observes arrival order.
+        :meth:`exchange` found a read that observes arrival order. An
+        unstable plain bucket over a hash partitioner keys on the
+        record's own hash instead of a pid (:meth:`_hash_order`).
 
         Returns ``(sr, counts, offsets)`` in ``bucket_records``'s
         contract; counts are post-filter/post-combine, so the existing
@@ -699,6 +702,11 @@ class ShuffleExchange:
         wire change."""
         from sparkrdma_tpu.kernels.aggregate import map_side_combine_cols
 
+        order = self._hash_order(partitioner, num_parts, combine,
+                                 row_filter, kw_idx is not None,
+                                 records.shape[0], stable)
+        if order is not None:
+            return bucket_records_hash_keyed(records, order, num_parts)
         pids = partitioner(records).astype(jnp.int32)
         if row_filter is not None:
             pids = jnp.where(row_filter(records), pids,
@@ -1521,17 +1529,37 @@ class ShuffleExchange:
                 records, partitioner, plan, num_parts, shuffle_id,
                 sort_key_words, aggregator, float_payload, use_combine,
                 row_filter, keep_words, stable)
-        self._count_bucket_sort(plan, num_parts, use_combine, row_filter,
-                                keep_words, records.shape[0], stable)
+        self._count_bucket_sort(plan, partitioner, num_parts, use_combine,
+                                row_filter, keep_words, records.shape[0],
+                                stable)
         return res
 
-    def _count_bucket_sort(self, plan, num_parts, use_combine, row_filter,
-                           keep_words, w, stable) -> None:
+    def _hash_order(self, partitioner, num_parts, combine, row_filter,
+                    projected, w, stable):
+        """The partitioner's :class:`~sparkrdma_tpu.exchange.partitioners.
+        HashOrder` where the map side buckets by it
+        (:func:`bucket_records_hash_keyed`), else ``None``: a hash
+        partitioner, more than one partition, an unstable plain sort,
+        no map-side combine, no filter (its sentinel pid is no hash)
+        and no projection."""
+        order = getattr(partitioner, "hash_order", None)
+        if (order is None or num_parts == 1 or combine
+                or row_filter is not None or projected):
+            return None
+        strategy = self.conf.sort_strategy(w)
+        if strategy.kind != "plain" or strategy.sorts_stably(stable):
+            return None
+        return order
+
+    def _count_bucket_sort(self, plan, partitioner, num_parts, use_combine,
+                           row_filter, keep_words, w, stable) -> None:
         """Counts the dispatched program's map-side bucket sort:
         ``exchange.bucket_sort.unstable`` where it dropped stability,
         ``exchange.bucket_sort.stable`` for every other program that
         buckets more than one partition (a stable plain or packed sort,
-        the wide branch, the map-side combine's own sort). A single
+        the wide branch, the map-side combine's own sort), and
+        ``exchange.bucket_sort.hash_keyed`` besides where the unstable
+        sort keyed on the record's hash (:meth:`_hash_order`). A single
         partition is bucketed by no sort: ``bucket_records`` returns it
         whole, and the one-chip, one-round program skips the map side."""
         if num_parts == 1 and (row_filter is None or (
@@ -1542,6 +1570,9 @@ class ShuffleExchange:
             w_eff).sorts_stably(stable))
         self.metrics.counter("exchange.bucket_sort.unstable" if unstable
                              else "exchange.bucket_sort.stable").inc()
+        if self._hash_order(partitioner, num_parts, use_combine, row_filter,
+                            keep_words is not None, w, stable) is not None:
+            self.metrics.counter("exchange.bucket_sort.hash_keyed").inc()
 
     def _dispatch_fused(self, records, partitioner, plan, num_parts,
                         shuffle_id, sort_key_words, aggregator,

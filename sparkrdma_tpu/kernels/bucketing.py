@@ -15,6 +15,10 @@ Here the same two steps happen in HBM, on COLUMNAR record batches
   keyed on destination partition, every word column riding along as a
   value — the "data file" (bucketed columns) and "index file"
   (counts/offsets) in one fused pass.
+  :func:`bucket_records_hash_keyed` is its form for a hash partitioner
+  on a read that keeps no order within a partition: the record's own
+  hash order key takes the place of its last key word, so no partition
+  id rides the sort.
 - :func:`fill_round_slots` = RdmaMappedFile + the fetcher's block
   aggregation: carve the bucketed columns into fixed-capacity
   per-destination windows for exchange round ``r``. Each window is a
@@ -38,6 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from sparkrdma_tpu.exchange.partitioners import HashOrder
 from sparkrdma_tpu.kernels.sort import SortStrategy, sort_by_lead_cols
 from sparkrdma_tpu.utils.profiling import device_phase
 
@@ -150,11 +155,32 @@ def bucket_records(
     part_ids = part_ids.astype(jnp.int32)
     sorted_ids, bucketed = sort_by_lead_cols(records, part_ids, strategy,
                                              stable)
-    counts = histogram_pids(part_ids, num_parts, sorted_ids=sorted_ids)
-    offsets = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(counts)[:-1].astype(jnp.int32)]
-    )
-    return bucketed, counts, offsets
+    return (bucketed,) + bucket_sorted_counts(sorted_ids, num_parts)
+
+
+def bucket_records_hash_keyed(
+    records: jax.Array, order: HashOrder, num_parts: int
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """:func:`bucket_records` for a hash partitioner, unstable, on the
+    plain strategy, with the partition id carried INSIDE the record.
+
+    The hash's order key (:class:`~sparkrdma_tpu.exchange.partitioners.
+    HashOrder`) sorts by partition first and is a bijection of the last
+    key word given the others, so it replaces that word in the sort:
+    ``W`` operands, where the pid-keyed sort carries ``W + 1``. After
+    the sort the pid and the last key word are rebuilt elementwise and
+    the word goes back to its row. Same contract and result as
+    ``bucket_records(records, pids, num_parts, stable=False)`` but for
+    the order of records within one partition.
+    """
+    k, w = order.key_words, records.shape[0]
+    rest = tuple(records[i] for i in range(w) if i != k - 1)
+    out = lax.sort((order.order_key(records),) + rest, num_keys=1,
+                   is_stable=False)
+    lead = out[1:k]
+    pids, last = order.invert(out[0], lead)
+    bucketed = jnp.stack(lead + (last,) + out[k:])
+    return (bucketed,) + bucket_sorted_counts(pids, num_parts)
 
 
 def bucket_sorted_counts(
@@ -335,5 +361,6 @@ def compact_segments(
     return packed, total
 
 
-__all__ = ["bucket_records", "bucket_sorted_counts", "fill_round_slots",
+__all__ = ["bucket_records", "bucket_records_hash_keyed",
+           "bucket_sorted_counts", "fill_round_slots",
            "fill_round_slots_dest_major", "compact_segments"]

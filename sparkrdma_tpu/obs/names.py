@@ -57,6 +57,7 @@ COUNTERS = frozenset({
     "exchange.records",
     "exchange.bucket_sort.stable",
     "exchange.bucket_sort.unstable",
+    "exchange.bucket_sort.hash_keyed",
     "combine.gate_on",
     "combine.gate_off",
     "combine.fallbacks",

@@ -6,7 +6,9 @@ import pytest
 from sparkrdma_tpu.kernels import (bucket_records, compact_segments,
                                    fill_round_slots,
                                    fill_round_slots_dest_major)
-from sparkrdma_tpu.kernels.bucketing import histogram_pids
+from sparkrdma_tpu.exchange.partitioners import hash_partitioner
+from sparkrdma_tpu.kernels.bucketing import (bucket_records_hash_keyed,
+                                             histogram_pids)
 from sparkrdma_tpu.kernels.sort import SortStrategy
 
 
@@ -71,6 +73,37 @@ def test_bucket_records_unstable_keeps_index_and_multisets(rng, strategy):
         _cols(rows), jnp.asarray(pids_np), p, strategy)
     np.testing.assert_array_equal(np.asarray(counts), np.asarray(s_counts))
     np.testing.assert_array_equal(np.asarray(offs), np.asarray(s_offs))
+
+
+@pytest.mark.parametrize("val_words", [0, 3])
+@pytest.mark.parametrize("key_words", [1, 2])
+@pytest.mark.parametrize("p", [2, 200, 256, 4096])
+def test_bucket_records_hash_keyed_matches_pid_keyed(rng, p, key_words,
+                                                     val_words):
+    """Keying the bucket sort on the record's hash order key instead of
+    a pid operand changes nothing but the order within a partition:
+    counts and offsets equal the unstable pid-keyed bucket's, and each
+    partition's run holds the same records, every word bit-exact."""
+    n = 3000
+    rows = rng.integers(0, 2**32, size=(n, key_words + val_words),
+                        dtype=np.uint32)
+    rows[:4, :key_words] = [[0], [2**32 - 1], [0], [2**32 - 1]]
+    rows[:2] = rows[2:4]                  # duplicate records
+    part = hash_partitioner(p, key_words)
+    cols = _cols(rows)
+    sr, counts, offs = jax.jit(
+        lambda c: bucket_records_hash_keyed(c, part.hash_order, p))(cols)
+    r_sr, r_counts, r_offs = bucket_records(cols, part(cols), p,
+                                            stable=False)
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(r_counts))
+    np.testing.assert_array_equal(np.asarray(offs), np.asarray(r_offs))
+    assert sr.shape == r_sr.shape and sr.dtype == r_sr.dtype
+    # each record sits in its partition's run; per run, the same multiset
+    run = np.repeat(np.arange(p), np.asarray(counts))
+    np.testing.assert_array_equal(np.asarray(part(sr)), run)
+    got = np.column_stack([run, np.asarray(sr).T])
+    ref = np.column_stack([run, np.asarray(r_sr).T])
+    np.testing.assert_array_equal(_canon(got), _canon(ref))
 
 
 def test_fill_round_slots_covers_all_records_across_rounds(rng):

@@ -1,3 +1,5 @@
+import itertools
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -186,3 +188,46 @@ def test_sort_by_lead_cols_strategies_agree(rng, name):
     assert got_lead.dtype == jnp.int32
     np.testing.assert_array_equal(np.asarray(got_lead), lead[order])
     np.testing.assert_array_equal(np.asarray(got), cols[:, order])
+
+
+def _np_hash(cols, key_words):
+    """numpy reference of ``hash_partitioner``'s mix, in u64 arithmetic."""
+    h = np.zeros(cols.shape[1], np.uint64)
+    for w in range(key_words):
+        h = ((h ^ cols[w].astype(np.uint64)) * 2654435761) & 0xFFFFFFFF
+    return h ^ (h >> 16)
+
+
+@pytest.mark.parametrize("key_words", [1, 2, 3])
+@pytest.mark.parametrize("p", [2, 200, 256, 4096])
+def test_hash_order_key_inverts_and_sorts_by_partition(rng, p, key_words):
+    """The hash partitioner's order key is ``r*q + min(r, m) + h // P``
+    with ``r = h mod P`` (a rotate when P is a power of two), so sorting
+    by it groups records by ascending partition; the inverse rebuilds
+    the partition and the last key word bit for bit, edge words too."""
+    from sparkrdma_tpu.exchange.partitioners import hash_partitioner
+
+    n = 4096
+    cols = rng.integers(0, 2**32, size=(key_words, n), dtype=np.uint32)
+    edge = np.array([0, 2**32 - 1, 1, 2**31], np.uint32)
+    grid = np.array(list(itertools.product(edge, repeat=key_words)),
+                    np.uint32).T                 # every edge-word record
+    cols[:, :grid.shape[1]] = grid
+    part = hash_partitioner(p, key_words)
+    order = part.hash_order
+    assert order.key_words == key_words
+    f = np.asarray(order.order_key(jnp.asarray(cols)))
+    h = _np_hash(cols, key_words)
+    q, m = divmod(1 << 32, p)
+    r = h % p
+    np.testing.assert_array_equal(
+        f, (r * q + np.minimum(r, m) + h // p).astype(np.uint32))
+    np.testing.assert_array_equal(np.asarray(part(jnp.asarray(cols))),
+                                  r.astype(np.int32))
+    s = np.argsort(f, kind="stable")
+    lead = tuple(jnp.asarray(cols[i, s]) for i in range(key_words - 1))
+    pid, last = order.invert(jnp.asarray(f[s]), lead)
+    assert pid.dtype == jnp.int32 and last.dtype == jnp.uint32
+    np.testing.assert_array_equal(np.asarray(pid), r[s].astype(np.int32))
+    assert np.all(np.diff(np.asarray(pid)) >= 0)
+    np.testing.assert_array_equal(np.asarray(last), cols[-1, s])

@@ -130,6 +130,13 @@ def _built(m, kind):
     return m.metrics.counter(f"exchange.programs_built.{kind}").value
 
 
+def _keep_even(records):
+    return (records[2] & 1) == 0
+
+
+_keep_even.cache_key = ("keep_even",)
+
+
 def _rows(seed, parts):
     return np.random.default_rng(seed).integers(
         0, 2**32, size=(parts * N_LOCAL, 4), dtype=np.uint32)
@@ -245,17 +252,64 @@ def test_manager_jobs_count_their_bucket_sort(tmp_path, job, kind):
     assert counts[kind] == 2 and sum(counts.values()) == 2, counts
 
 
+#: reads of a hash-partitioned job: the reader's arguments, the conf,
+#: whether every key is one (a hot partition), and whether the map side
+#: buckets on the record's hash
+HASH_READS = {
+    "unordered": ({}, {}, False, True),
+    "streaming": ({}, {"slot_records": 8, "max_rounds_in_flight": 1},
+                  True, True),
+    "key_ordered": ({"key_ordering": True}, {}, False, False),
+    "reduce_by_key": ({"aggregator": "sum"}, {}, False, False),
+    "filtered": ({"row_filter": _keep_even}, {}, False, False),
+    "split": ({}, {"slot_records": 8, "max_rounds": 1}, True, False),
+}
+
+
+@pytest.mark.parametrize("read", sorted(HASH_READS))
+def test_manager_jobs_count_hash_keyed_bucket(tmp_path, read):
+    """An unordered hash repartition buckets on the record's hash and
+    counts ``exchange.bucket_sort.hash_keyed`` once a job, in either
+    regime; a key-ordered read, an aggregation, a filtered read and a
+    skew-split partitioner keep their pid-keyed sort and count none."""
+    reader_kw, conf_kw, hot, keyed = HASH_READS[read]
+    conf = ShuffleConf(**{"slot_records": 64,
+                          "metrics_sink": str(tmp_path / "j.jsonl"),
+                          **conf_kw})
+    with ShuffleManager(MeshRuntime(conf), conf) as m:
+        parts = m.runtime.num_partitions
+        rows = _rows(14, parts)
+        if hot:
+            rows[:, :KW] = 7                 # one hot partition: rounds > 1
+        x = m.runtime.shard_records(rows)
+        for sid in (1, 2):
+            h = m.register_shuffle(sid, parts, hash_partitioner(parts, KW))
+            plan = m.get_writer(h).write(x).stop(True)
+            assert (plan.split_factor > 1) == (read == "split")
+            assert (plan.num_rounds > 1) == (read == "streaming")
+            out, totals = m.get_reader(h, **reader_kw).read()
+            jax.block_until_ready((out, totals))
+            m.unregister_shuffle(sid)
+        counts = {k: m.metrics.counter(f"exchange.bucket_sort.{k}").value
+                  for k in ("stable", "unstable", "hash_keyed")}
+    assert counts["hash_keyed"] == (2 if keyed else 0), counts
+    assert counts["stable"] + counts["unstable"] == 2, counts
+    if keyed:
+        assert counts["unstable"] == 2, counts
+
+
 #: each cell's sort form: its conf, partitions, key-sort words, the
 #: phase its sort runs in, and the operand types of that sort as the
 #: chip's trace names them. TeraSort's tight tail sorts 3 key words
 #: and the wide form's index; repartition(256)'s unordered bucket sort
-#: carries the s32 partition id and the 2 record words, no index.
+#: carries the hash order key in place of the last key word, and the
+#: first: no partition id, no index.
 CELL_FORMS = {
     "terasort": (dict(key_words=3, val_words=22, pack_sort_min_payload=0,
                       wide_sort_ride_words=0), 1, 3, "sr_sort_keys",
                  "(u32[{n}], u32[{n}], u32[{n}], s32[{n}])"),
     "repartition": (dict(key_words=2, val_words=0), 16, 0, "sr_bucket",
-                    "(s32[{n}], u32[{n}], u32[{n}])"),
+                    "(u32[{n}], u32[{n}])"),
 }
 
 
